@@ -2,6 +2,7 @@
 sample, the paper's two metrics (Table 2a, Fig 2b)."""
 from __future__ import annotations
 
+import os
 import time
 
 import jax
@@ -9,6 +10,19 @@ import numpy as np
 from jax import random
 
 from repro.core.infer import MCMC, NUTS, effective_sample_size
+
+CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), ".jax_cache")
+
+
+def use_compile_cache():
+    """Persist compiled programs across processes: in
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it itself), else
+    in a fixed directory of this checkout — a fixed path, because the path
+    is part of what a later run must find again.  Returns the directory."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
 def run_nuts(model, model_args=(), model_kwargs=None, *, num_warmup,

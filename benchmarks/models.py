@@ -111,7 +111,7 @@ def covtype_data(rng_key=None, n=581_012, d=54):
     true_w = random.normal(k2, (d,)) * 0.5
     logits = x @ true_w
     y = dist.Bernoulli(logits=logits).sample(rng_key=k3)
-    return {"x": x, "y": y.astype(jnp.float32)}
+    return {"x": x, "y": y.astype(jnp.float32), "true_w": true_w}
 
 
 def logreg_model(x, y=None):

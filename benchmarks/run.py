@@ -1,10 +1,13 @@
-"""Run every benchmark (one per paper table/figure) + the roofline table.
+"""Run every benchmark (one per paper table/figure).
 
 ``python -m benchmarks.run``          — full paper-spec settings
 ``python -m benchmarks.run --quick``  — reduced step counts (CI / smoke)
 ``--profile``                         — wrap each section in a
                                         ``jax.profiler.trace`` (perfetto
                                         dirs under results/profile/)
+
+Compiled programs persist in ``JAX_COMPILATION_CACHE_DIR`` when it is set,
+else in ``.jax_cache/`` at the repository root.
 """
 import contextlib
 import json
@@ -95,6 +98,8 @@ def main():
     out = {}
     previous = _previous_headlines()
 
+    from benchmarks.harness import use_compile_cache
+    use_compile_cache()
     # every summary records where it was measured (the trajectory in
     # BENCH_<n>.json is only comparable within one environment)
     from repro.obs import collect_environment
@@ -134,16 +139,6 @@ def main():
         print("=" * 70, flush=True)
         with profile(key):
             out[key] = fn(quick=quick)
-
-    print("=" * 70)
-    print("Roofline (from dry-run artifacts; see EXPERIMENTS.md)")
-    print("=" * 70, flush=True)
-    try:
-        from benchmarks import roofline
-        roofline.main()
-        out["roofline_rows"] = roofline.table(roofline.load())
-    except Exception as e:  # dry-run artifacts may not exist yet
-        print(f"[roofline skipped: {e}]")
 
     out["total_wall_s"] = time.time() - t0
     if previous is not None:

@@ -144,7 +144,10 @@ class StickBreakingTransform(Transform):
     codomain = constraints.simplex
 
     def _offset(self, size):
-        return jnp.log(jnp.arange(size, 0, -1.0))
+        # [size, ..., 1] from an iota, not from a host array: jax 0.9 cannot
+        # pass a host-array constant to a program as an argument
+        # (JAX_USE_SIMPLIFIED_JAXPR_CONSTANTS=1)
+        return jnp.log(size - jnp.arange(size, dtype=jnp.float32))
 
     def __call__(self, x):
         z = jax.nn.sigmoid(x - self._offset(x.shape[-1]))

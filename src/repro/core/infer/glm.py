@@ -18,10 +18,11 @@ i.e. the exact prior + transform log-det through the normal machinery and
 the likelihood through the fused kernel, wrapped in ``jax.custom_vjp`` so
 the backward pass is the O(d) residual product the kernel already computed
 — instead of XLA's n-vector reverse chains.  Any structural surprise
-(non-affine predictor, probs-parametrized Bernoulli, non-constant Normal
-scale, site-level scale/mask, enumeration marks) falls back to the plain
-potential with a warning: the fusion is an optimization, never a semantics
-change.
+(non-affine or untraceable predictor, probs-parametrized Bernoulli,
+non-constant Normal scale, site-level scale/mask, enumeration marks) falls
+back to the plain potential with a warning: the fusion is an optimization,
+never a semantics change.  An error raised by the kernel itself (or by its
+lowering) is not a structural surprise and propagates.
 """
 from __future__ import annotations
 
@@ -69,13 +70,10 @@ def _make_sharded_nll(x, y, offset, scale, family, data_shards):
     accumulation and breaks bit-identity.
     """
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..._compat import ensure_optimization_barrier_batch_rule
     from ...kernels.glm_potential import glm_potential_partials
     from .hmc_util import chain_sum
-    ensure_optimization_barrier_batch_rule()
     S = int(data_shards)
 
     def _value_and_grad(zflat):
@@ -102,10 +100,10 @@ def _make_sharded_nll(x, y, offset, scale, family, data_shards):
                 ag = lax.all_gather(lg, axis, axis=0, tiled=True)
                 return chain_sum(av), chain_sum(ag)
 
-            out = shard_map(
+            out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis, None), P(axis), P(axis), P()),
-                out_specs=(P(), P()), check_rep=False)(x, y, offset, zflat)
+                out_specs=(P(), P()), check_vma=False)(x, y, offset, zflat)
         else:
             vals, grads = lax.optimization_barrier(glm_potential_partials(
                 x, y, zflat, offset, scale, family, data_shards=S))
@@ -141,7 +139,21 @@ def maybe_fuse_glm_potential(model, model_args, model_kwargs, transforms,
     ``data_shards=S`` additionally gives the likelihood term a static
     S-shard fold structure (see :func:`_make_sharded_nll`) and marks the
     returned potential with ``potential.data_shards = S`` so the executor
-    and RPL204 can see it is shard-aware."""
+    and RPL204 can see it is shard-aware.
+
+    Extraction and verification run their matmuls in full f32: a TPU's
+    default precision multiplies f32 in one bf16 pass, which would read
+    the design matrix back rounded and fail the affinity probe.  The
+    returned potential is traced later, at the caller's precision."""
+    with jax.default_matmul_precision("highest"):
+        return _fuse_glm_potential(model, model_args, model_kwargs,
+                                   transforms, unravel_fn, flat_proto,
+                                   model_trace, potential_flat, data_shards)
+
+
+def _fuse_glm_potential(model, model_args, model_kwargs, transforms,
+                        unravel_fn, flat_proto, model_trace, potential_flat,
+                        data_shards):
     marked = [name for name, site in model_trace.items()
               if site["type"] == "sample" and site["is_observed"]
               and site["infer"].get("potential") == "glm"]
@@ -211,7 +223,9 @@ def maybe_fuse_glm_potential(model, model_args, model_kwargs, transforms,
                 if not bool(jnp.all(sz == s)):
                     return _fallback(name, "the Normal scale depends on "
                                      "the latents")
-    except Exception as e:  # noqa: BLE001 — tracing surprises => plain path
+    except (jax.errors.JAXTypeError, jax.errors.JAXIndexError) as e:
+        # the predictor is not a traceable function of the latents (Python
+        # control flow on a traced value, a leaked tracer, ...)
         return _fallback(name, f"predictor extraction failed "
                          f"({type(e).__name__}: {e})")
 
@@ -248,15 +262,12 @@ def maybe_fuse_glm_potential(model, model_args, model_kwargs, transforms,
                                  transforms, unravel_fn(zflat))
         return prior + nll(zflat)
 
-    # end-to-end verification: fused == plain at a probe point
-    try:
-        zp = jax.random.normal(jax.random.PRNGKey(2), flat_proto.shape) * 0.5
-        a, b = fused_potential(zp), potential_flat(zp)
-        if not bool(jnp.abs(a - b) <= 1e-4 * (1.0 + jnp.abs(b))):
-            return _fallback(name, f"fused potential mismatch ({a} vs {b})")
-    except Exception as e:  # noqa: BLE001
-        return _fallback(name, f"fused potential verification failed "
-                         f"({type(e).__name__}: {e})")
+    # end-to-end verification: fused == plain at a probe point.  An error
+    # raised here comes from the kernel or its lowering and propagates.
+    zp = jax.random.normal(jax.random.PRNGKey(2), flat_proto.shape) * 0.5
+    a, b = fused_potential(zp), potential_flat(zp)
+    if not bool(jnp.abs(a - b) <= 1e-4 * (1.0 + jnp.abs(b))):
+        return _fallback(name, f"fused potential mismatch ({a} vs {b})")
     if data_shards is not None:
         # marker the setup layer / RPL204 use to tell shard-aware potentials
         # from monolithic ones (see kernel_api.KernelSetup.data_axis)

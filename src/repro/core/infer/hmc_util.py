@@ -208,23 +208,22 @@ def shared_draw(x):
 
     Cross-chain kernels draw chain-batched randomness from one shared key —
     ``random.normal(key, (C, D))`` or a ``vmap`` over ``random.split(key,
-    C)``.  jax's default (non-partitionable) threefry lowering pairs flat
-    counter indices ``(i, i + n/2)``; when GSPMD partitions that flat range
-    over a 2-D inference mesh the pairing crosses shard boundaries and the
-    rewritten computation generates *different bits* than the unsharded
-    graph — not an ULP fusion effect, a different random stream.  Pinning
-    the draw's layout to fully-replicated makes every device compute the
-    whole (tiny, O(C·D)) draw exactly as the single-device graph does;
-    downstream consumers re-slice it.
+    C)``.  The installed jax lowers threefry partitionably
+    (``jax_threefry_partitionable`` is on by default): each value's counter
+    depends only on its own index, so a draw sharded over the inference mesh
+    yields the same bits as the unsharded one.  The replication constraint
+    keeps that true by construction rather than by the lowering: every
+    device computes the whole (tiny, O(C·D)) draw exactly as the
+    single-device graph does, and downstream consumers re-slice it.  (Under
+    the older non-partitionable lowering, GSPMD's counter rewrite produced
+    a different random stream on a 2-D mesh.)
 
     The trailing ``optimization_barrier`` fires in *every* graph (mesh or
     not): the replication constraint is itself a fusion boundary, so the
     unsharded graphs need the same boundary or the draw's consumers fuse
     (FMA-contract) differently and drift at ULP level.
     """
-    from repro._compat import ensure_optimization_barrier_batch_rule
     from repro.distributed.sharding import active_data_mesh
-    ensure_optimization_barrier_batch_rule()
     active = active_data_mesh()
     if active is not None:
         from jax.sharding import NamedSharding, PartitionSpec

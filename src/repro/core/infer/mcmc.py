@@ -268,12 +268,22 @@ class MCMC:
         potential closure reads the mesh via
         ``repro.distributed.sharding.active_data_mesh`` while the program is
         being traced — the compiled executable is mesh-specialized but the
-        KernelSetup stays mesh-agnostic and hashable.
+        KernelSetup stays mesh-agnostic and hashable.  Every parallel program
+        is also traced under the mesh as JAX's abstract mesh: the kernel
+        dispatch reads it, and keeps Pallas kernels (which the TPU compiler
+        cannot partition) out of the partitioned program except inside the
+        potential's ``shard_map`` body.
         """
-        if self.chain_method != "parallel" or setup.data_axis is None:
+        if self.chain_method != "parallel":
             return prog
         mesh = self._inference_mesh()
-        if setup.data_axis not in mesh.axis_names:
+        partitioned = prog
+
+        def prog(*args):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return partitioned(*args)
+
+        if setup.data_axis is None or setup.data_axis not in mesh.axis_names:
             return prog  # legacy 1-D chains mesh: potential folds locally
         from repro.distributed.sharding import use_inference_mesh
 

@@ -5,13 +5,24 @@ The enumeration subsystem's hot loop (``repro.core.infer.enum.markov``) runs
 time step inside ``lax.scan`` — the O(K^2) inner body of the O(T*K^2) forward
 algorithm.  Unfused, XLA materializes the (K, K) broadcast sum, the max, the
 exp and the log as separate HBM round-trips; this kernel does the whole
-contraction in one VMEM pass per batch row.
+contraction in one VMEM pass per tile of batch rows.
 
 The formula is written identically to :func:`repro.kernels.ref.enum_contract`
-(max, exp-sum, log, fully-masked columns pinned to -inf), and padding only
-ever adds exact ``-inf`` rows (``exp`` -> exact 0.0 terms) and ``-inf``
-columns (sliced off), so the kernel is bit-identical to the ref path in
-interpret mode — the same contract ``leapfrog_halfstep`` keeps.
+(max, strictly left-to-right exp-sum over the contracted axis, log,
+fully-masked columns pinned to -inf), so the kernel is bit-identical to the
+ref path in interpret mode — the same contract ``leapfrog_halfstep`` keeps.
+
+Layout.  The TPU compiler tiles the last two dims of every block in (8, 128)
+sublane x lane units.  The contracted axis therefore leads ``log_mat``
+(``(Ki, B, K)``) and is unrolled in the body, while the batch rows go on the
+sublanes in tiles of 8 and the output states on the lanes; the batch is
+padded to a multiple of 8 and the states to a multiple of 128, and both pads
+are sliced off the result.
+
+Gradients.  A ``pallas_call`` has no reverse-mode rule, and NUTS
+differentiates the marginal through every step of the scan, so the kernel is
+wrapped in ``jax.custom_vjp`` whose backward pass is the VJP of the ref
+oracle at the same inputs (the softmax-weighted cotangents).
 """
 from __future__ import annotations
 
@@ -22,25 +33,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-SUBLANE = 8    # f32 min tile rows
-LANE = 128     # lane width: last dim padded to a multiple of this
+from . import ref
+
+SUBLANE = 8    # f32 min tile rows: batch rows per grid step
+LANE = 128     # lane width: output states padded to a multiple of this
 
 
 def _kernel(alpha_ref, mat_ref, out_ref, *, compute_dtype):
-    alpha = alpha_ref[0].astype(compute_dtype)          # (Kip,)
-    mat = mat_ref[0].astype(compute_dtype)              # (Kip, Kp)
-    x = alpha[:, None] + mat
-    m = jnp.max(x, axis=0)                              # (Kp,)
+    alpha = alpha_ref[...].astype(compute_dtype)        # (SUBLANE, Ki)
+    # x_i = alpha[:, i] + mat[i] over the contracted states, each (SUBLANE, Kp)
+    xs = [alpha[:, i:i + 1] + mat_ref[i].astype(compute_dtype)
+          for i in range(mat_ref.shape[0])]
+    m = xs[0]
+    for x in xs[1:]:
+        m = jnp.maximum(m, x)
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = jnp.exp(x - m_safe[None, :])
     # left-to-right sequential sum: pinned order matches the ref oracle
-    # bit-for-bit, and padded rows only add exact +0.0 (exp(-inf))
-    s = e[0]
-    for i in range(1, e.shape[0]):
-        s = s + e[i]
+    s = jnp.exp(xs[0] - m_safe)
+    for x in xs[1:]:
+        s = s + jnp.exp(x - m_safe)
     out = jnp.where(jnp.isfinite(m), jnp.log(s) + m_safe,
                     -jnp.array(jnp.inf, compute_dtype))
-    out_ref[0] = out.astype(out_ref.dtype)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
 def _pad_to(n, mult):
@@ -49,36 +63,47 @@ def _pad_to(n, mult):
 
 def enum_contract(log_alpha, log_mat, *, interpret=False):
     """``(..., Ki) x (..., Ki, K) -> (..., K)`` logsumexp contraction."""
-    Ki, K = log_mat.shape[-2:]
+    Ki = log_mat.shape[-2]
     if log_alpha.shape[-1] != Ki:
         raise ValueError(
             f"enum_contract: log_alpha has {log_alpha.shape[-1]} states, "
             f"log_mat contracts over {Ki}")
+    return _contract(log_alpha, log_mat, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _contract(log_alpha, log_mat, interpret):
+    Ki, K = log_mat.shape[-2:]
     batch = jnp.broadcast_shapes(log_alpha.shape[:-1], log_mat.shape[:-2])
     out_dtype = jnp.result_type(log_alpha.dtype, log_mat.dtype)
     alpha = jnp.broadcast_to(log_alpha, batch + (Ki,)).astype(out_dtype)
     mat = jnp.broadcast_to(log_mat, batch + (Ki, K)).astype(out_dtype)
-    B = math.prod(batch) if batch else 1
-    alpha = alpha.reshape(B, Ki)
-    mat = mat.reshape(B, Ki, K)
-
-    kip, kp = _pad_to(Ki, SUBLANE), _pad_to(K, LANE)
-    neg_inf = jnp.array(-jnp.inf, out_dtype)
-    if kip != Ki:
-        alpha = jnp.pad(alpha, ((0, 0), (0, kip - Ki)),
-                        constant_values=neg_inf)
-    if (kip, kp) != (Ki, K):
-        mat = jnp.pad(mat, ((0, 0), (0, kip - Ki), (0, kp - K)),
-                      constant_values=neg_inf)
+    B = math.prod(batch)
+    bp, kp = _pad_to(B, SUBLANE), _pad_to(K, LANE)
+    alpha = jnp.pad(alpha.reshape(B, Ki), ((0, bp - B), (0, 0)))
+    mat = jnp.pad(jnp.moveaxis(mat.reshape(B, Ki, K), 1, 0),
+                  ((0, 0), (0, bp - B), (0, kp - K)),
+                  constant_values=-jnp.inf)
 
     compute_dtype = jnp.promote_types(out_dtype, jnp.float32)
     out = pl.pallas_call(
         functools.partial(_kernel, compute_dtype=compute_dtype),
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, kip), lambda b: (b, 0)),
-                  pl.BlockSpec((1, kip, kp), lambda b: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, kp), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, kp), out_dtype),
-        interpret=interpret,
+        grid=(bp // SUBLANE,),
+        in_specs=[pl.BlockSpec((SUBLANE, Ki), lambda b: (b, 0)),
+                  pl.BlockSpec((Ki, SUBLANE, kp), lambda b: (0, b, 0))],
+        out_specs=pl.BlockSpec((SUBLANE, kp), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((bp, kp), out_dtype),
+        interpret=interpret, name="enum_contract",
     )(alpha, mat)
-    return out[:, :K].reshape(batch + (K,))
+    return out[:B, :K].reshape(batch + (K,))
+
+
+def _contract_fwd(log_alpha, log_mat, interpret):
+    return _contract(log_alpha, log_mat, interpret), (log_alpha, log_mat)
+
+
+def _contract_bwd(interpret, res, ct):
+    return jax.vjp(ref.enum_contract, *res)[1](ct)
+
+
+_contract.defvjp(_contract_fwd, _contract_bwd)

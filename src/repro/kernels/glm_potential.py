@@ -6,8 +6,8 @@ the forward log-density and a second (plus an n-vector residual chain) for
 the backward.  Both reductions consume the *same* residual against the same
 ``x``, so one HBM read of the design matrix can serve value AND gradient —
 that is what this kernel does.  The grid walks n-tiles; each tile computes
-its logits on the MXU, masks padded rows, and accumulates a scalar nll and
-a (1, d) gradient row into the (sequential) grid outputs.
+its logits on the MXU, masks the rows past ``n``, and accumulates a scalar
+nll and a (1, d) gradient row into the (sequential) grid outputs.
 
 Supported families mirror the model-side detection in
 ``repro.core.infer.glm``: ``bernoulli_logit`` (exact negation of
@@ -23,19 +23,22 @@ from jax.experimental import pallas as pl
 
 _HALF_LOG_2PI = 0.5 * 1.8378770664093453
 BLOCK_N = 2048
-_SUBLANE = 8
-_LANE = 128
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(scale_ref, x_ref, y_ref, off_ref, w_ref, nll_ref, grad_ref, *,
             family, bn, n):
     i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)                       # (bn, dp)
-    y = y_ref[...].astype(jnp.float32)                       # (bn, 1)
-    w = w_ref[...].astype(jnp.float32)                       # (dp, 1)
-    logits = jax.lax.dot(x, w) + off_ref[...].astype(jnp.float32)
+    # the last tile may run past row n: what it reads there is undefined,
+    # so those rows are zeroed before they reach a sum or a product
     row = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
-    valid = row < n                                          # mask padding
+    valid = row < n
+    x = jnp.where(valid, x_ref[...].astype(jnp.float32), 0.0)  # (bn, d)
+    y = jnp.where(valid, y_ref[...].astype(jnp.float32), 0.0)  # (bn, 1)
+    off = jnp.where(valid, off_ref[...].astype(jnp.float32), 0.0)
+    w = w_ref[...].astype(jnp.float32)                         # (d, 1)
+    # full f32 on the MXU: Mosaic's default may multiply f32 in bf16
+    logits = jax.lax.dot(x, w, precision=_F32) + off
     if family == "bernoulli_logit":
         terms = jax.nn.softplus(logits) - y * logits
         resid = jax.nn.sigmoid(logits) - y
@@ -47,8 +50,9 @@ def _kernel(scale_ref, x_ref, y_ref, off_ref, w_ref, nll_ref, grad_ref, *,
     terms = jnp.where(valid, terms, 0.0)
     resid = jnp.where(valid, resid, 0.0)
     part_nll = jnp.sum(terms).reshape(1, 1)
-    part_grad = jax.lax.dot_general(                         # x^T @ resid
-        resid, x, dimension_numbers=(((0,), (0,)), ((), ())))  # (1, dp)
+    part_grad = jax.lax.dot_general(                           # x^T @ resid
+        resid, x, dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_F32)                                        # (1, d)
 
     @pl.when(i == 0)
     def _init():
@@ -66,41 +70,35 @@ def glm_potential_grad(x, y, w, offset=None, scale=None,
 
     ``offset`` shifts the linear predictor (None = 0); ``scale`` is the
     Normal noise scale (ignored for bernoulli_logit).  ``block_n`` is the
-    n-tile size — tuning only, trailing-defaulted (RPL202).
+    n-tile size, a multiple of 8 — tuning only, trailing-defaulted
+    (RPL202).  The kernel reads ``x`` where it lies: a tile spans all ``d``
+    columns, and the last tile masks the rows past ``n``, so no padded copy
+    of the design matrix is made per call.
     """
     if family not in ("bernoulli_logit", "normal"):
         raise ValueError(f"unknown GLM family: {family!r}")
     n, d = x.shape
-    bn = min(block_n, n)
-    bn += (-bn) % _SUBLANE
-    npad = (-n) % bn
-    dpad = (-d) % _LANE
+    bn = n if n <= block_n else block_n
     offset = jnp.zeros((n,), jnp.float32) if offset is None else offset
-    if npad or dpad:
-        x = jnp.pad(x, ((0, npad), (0, dpad)))
-        y = jnp.pad(y, (0, npad))
-        offset = jnp.pad(offset, (0, npad))
-    wp = jnp.pad(w, (0, dpad)).reshape(-1, 1)
-    nrows, dp = x.shape
     scale_arr = jnp.asarray(1.0 if scale is None else scale,
                             jnp.float32).reshape(1, 1)
     nll, grad = pl.pallas_call(
         functools.partial(_kernel, family=family, bn=bn, n=n),
-        grid=(nrows // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),          # scale
-            pl.BlockSpec((bn, dp), lambda i: (i, 0)),        # x tile
+            pl.BlockSpec((bn, d), lambda i: (i, 0)),         # x tile
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),         # y tile
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),         # offset tile
-            pl.BlockSpec((dp, 1), lambda i: (0, 0)),         # w (full)
+            pl.BlockSpec((d, 1), lambda i: (0, 0)),          # w (full)
         ],
         out_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, dp), lambda i: (0, 0))],
+                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((1, dp), jnp.float32)],
-        interpret=interpret,
-    )(scale_arr, x, y.reshape(-1, 1), offset.reshape(-1, 1), wp)
-    return nll[0, 0].astype(w.dtype), grad[0, :d].astype(w.dtype)
+                   jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        interpret=interpret, name="glm_potential_grad",
+    )(scale_arr, x, y.reshape(-1, 1), offset.reshape(-1, 1), w.reshape(-1, 1))
+    return nll[0, 0].astype(w.dtype), grad[0].astype(w.dtype)
 
 
 def glm_potential_partials(x, y, w, offset=None, scale=None,

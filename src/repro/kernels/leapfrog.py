@@ -11,8 +11,13 @@ The sign convention matches ``hmc_util.velocity_verlet`` exactly (``g`` is
 the gradient of the *potential*), so the kernel drops into the integrator
 with no extra negation pass.  ``eps`` is a traced operand — NUTS flips its
 sign when growing the trajectory leftwards and adaptation rescales it every
-warmup step — so it is shipped as a tiny (1,) array rather than baked into
+warmup step — so it is shipped as a tiny (1, 1) array rather than baked into
 the kernel at trace time.
+
+The TPU compiler tiles the last two dims of every block in (8, 128) units,
+and ``vmap`` over chains (the executor's batching) adds a leading block dim.
+So the (D,) vectors travel as (rows, 128) slabs and ``eps`` as (1, 1): under
+``vmap`` each chain's blocks keep whole-array or tile-aligned last two dims.
 """
 from __future__ import annotations
 
@@ -23,11 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK = 4096
+_SUBLANE = 8
+_LANE = 128
 
 
 def _kernel(eps_ref, z_ref, r_ref, g_ref, minv_ref, znew_ref, rnew_ref, *,
             compute_dtype):
-    eps = eps_ref[0].astype(compute_dtype)
+    eps = eps_ref[0, 0].astype(compute_dtype)
     r = r_ref[...].astype(compute_dtype)
     g = g_ref[...].astype(compute_dtype)
     z = z_ref[...].astype(compute_dtype)
@@ -45,26 +52,28 @@ def leapfrog_halfstep(z, r, grad, m_inv, eps, *, block=BLOCK,
     kernel stays a drop-in replacement for the ref oracle (RPL202).
     """
     D = z.shape[0]
-    blk = min(block, D)
-    pad = (-D) % blk
-    if pad:
-        z, r, grad, m_inv = (jnp.pad(a, (0, pad)) for a in (z, r, grad,
-                                                            m_inv))
-    n = z.shape[0]
+    rows = -(-D // _LANE)
+    # one block of every row when it fits, else sublane-aligned row tiles
+    br = rows if rows * _LANE <= block else max(
+        _SUBLANE, block // _LANE // _SUBLANE * _SUBLANE)
+    rows += (-rows) % br
+    pad = rows * _LANE - D
+    z, r, grad, m_inv = (jnp.pad(a, (0, pad)).reshape(rows, _LANE)
+                         for a in (z, r, grad, m_inv))
     # accumulate low-precision inputs in f32, but never truncate f64 chains
     compute_dtype = jnp.promote_types(z.dtype, jnp.float32)
-    eps = jnp.asarray(eps, compute_dtype).reshape(1)
+    eps = jnp.asarray(eps, compute_dtype).reshape(1, 1)
+    tile = pl.BlockSpec((br, _LANE), lambda i: (i, 0))
     zf, rf = pl.pallas_call(
         functools.partial(_kernel, compute_dtype=compute_dtype),
-        grid=(n // blk,),
-        in_specs=[pl.BlockSpec((1,), lambda i: (0,))]
-        + [pl.BlockSpec((blk,), lambda i: (i,))] * 4,
-        out_specs=[pl.BlockSpec((blk,), lambda i: (i,))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((n,), z.dtype),
-                   jax.ShapeDtypeStruct((n,), r.dtype)],
-        interpret=interpret,
+        grid=(rows // br,),
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0))] + [tile] * 4,
+        out_specs=[tile] * 2,
+        out_shape=[jax.ShapeDtypeStruct((rows, _LANE), z.dtype),
+                   jax.ShapeDtypeStruct((rows, _LANE), r.dtype)],
+        interpret=interpret, name="leapfrog_halfstep",
     )(eps, z, r, grad, m_inv)
-    return zf[:D], rf[:D]
+    return zf.reshape(-1)[:D], rf.reshape(-1)[:D]
 
 
 def leapfrog_halfstep_ref(z, r, grad, m_inv, eps):
@@ -82,10 +91,6 @@ def leapfrog_halfstep_ref(z, r, grad, m_inv, eps):
 # classic half-kick, 1.0 the merged full kick used between interior steps of
 # a trajectory (two adjacent half-kicks fused into one HBM pass).
 # --------------------------------------------------------------------------
-
-_SUBLANE = 8
-_LANE = 128
-
 
 def _batch_kernel(s_ref, z_ref, r_ref, g_ref, minv_ref, znew_ref, rnew_ref,
                   *, compute_dtype):
@@ -128,7 +133,7 @@ def leapfrog_halfstep_batch(z, r, grad, m_inv, eps, kick=0.5, *, block=BLOCK,
         out_specs=[pl.BlockSpec((cp, bd), lambda i: (0, i))] * 2,
         out_shape=[jax.ShapeDtypeStruct((cp, dp), z.dtype),
                    jax.ShapeDtypeStruct((cp, dp), r.dtype)],
-        interpret=interpret,
+        interpret=interpret, name="leapfrog_halfstep_batch",
     )(scalars, z, r, grad, m_inv)
     return zf[:C, :D], rf[:C, :D]
 

@@ -1,20 +1,36 @@
 """jit'd dispatch wrappers: one call site for Pallas kernels and jnp oracles.
 
-``use_pallas(True)`` (or env REPRO_USE_PALLAS=1) routes the hot ops through
-the Pallas TPU kernels in this package; the default (and the only option on
-the CPU backend, where Pallas TPU lowering is unavailable) is the pure-jnp
-reference path in :mod:`repro.kernels.ref`.  ``interpret=True`` is used by
-the test-suite to execute kernel bodies on CPU against the oracles.
+The route is chosen by the platform at trace time: on a TPU every op that
+has a kernel runs its Pallas TPU kernel; everywhere else (the CPU backend,
+where Pallas TPU lowering is unavailable) it runs the pure-jnp reference in
+:mod:`repro.kernels.ref`.  ``use_pallas(enable, interpret)`` overrides the
+choice inside a ``with`` block: ``use_pallas(True, interpret=True)`` is how
+the test-suite executes kernel bodies on CPU against the oracles, and
+``use_pallas(False)`` runs the oracles on a TPU.
+
+A program that XLA partitions over several devices cannot hold a Pallas TPU
+kernel (the compiler cannot split it), so the platform route reads the
+mesh of the tracing context: under a mesh of more than one device the ops
+take their oracles, except inside a ``shard_map`` body, where every device
+runs its own block and the kernels are legal.  A partitioned program
+declares its mesh while it is traced (``jax.set_mesh`` or
+``jax.sharding.use_abstract_mesh``); the MCMC executor does so for
+``chain_method="parallel"``.
 """
 from __future__ import annotations
 
+import math
 import os
 from contextlib import contextmanager
 from typing import NamedTuple, Optional, Tuple
 
+import jax
+
 from . import ref
 
-_STATE = {"pallas": os.environ.get("REPRO_USE_PALLAS", "0") == "1",
+# "pallas": None follows the platform; True/False or a set of op names is a
+# use_pallas override
+_STATE = {"pallas": None,
           "interpret": False,
           "ssd_inline": os.environ.get("REPRO_SSD_INLINE", "0") == "1"}
 
@@ -78,6 +94,11 @@ _CONTROL = frozenset({"use_pallas", "pallas_enabled", "ssd_inline"})
 
 @contextmanager
 def use_pallas(enable=True, interpret=False):
+    """Override the platform route inside the block: ``True`` runs every
+    op's kernel, ``False`` every oracle, and a collection of op names runs
+    those ops' kernels and the oracles of the rest."""
+    if not isinstance(enable, bool):
+        enable = frozenset(enable)
     old = dict(_STATE)
     _STATE.update(pallas=enable, interpret=interpret)
     try:
@@ -86,14 +107,40 @@ def use_pallas(enable=True, interpret=False):
         _STATE.update(old)
 
 
-def pallas_enabled():
-    return _STATE["pallas"]
+# Ops whose kernels the TPU compiler refuses (blocks that are not (8, 128)
+# tile-aligned, an operand layout Mosaic rejects) or that were never
+# compiled for the chip (rmsnorm: a row count that is not a multiple of its
+# block, a misaligned backward block): the platform route keeps them on
+# their oracle until the kernels are fixed, and only an explicit
+# use_pallas(True) runs them.
+_REFUSED_ON_TPU = frozenset({"attention", "rmsnorm", "softmax_xent",
+                             "ssd_scan"})
+
+
+def _partitioned():
+    """Whether the trace belongs to a program XLA partitions over several
+    devices: the tracing context's mesh spans more than one device along
+    axes that are not manual (a ``shard_map`` body's axes are)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return math.prod(size for name, size in mesh.shape.items()
+                     if name not in mesh.manual_axes) > 1
+
+
+def pallas_enabled(op):
+    """Whether ``op``, traced now, dispatches to its Pallas kernel."""
+    enable = _STATE["pallas"]
+    if enable is None:
+        return (jax.default_backend() == "tpu" and op not in _REFUSED_ON_TPU
+                and not _partitioned())
+    if isinstance(enable, bool):
+        return enable
+    return op in enable
 
 
 # ---------------------------------------------------------------------------
 
 def attention(q, k, v, *, causal=True, scale=None, window=0):
-    if _STATE["pallas"]:
+    if pallas_enabled("attention"):
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                window=window, interpret=_STATE["interpret"])
@@ -112,7 +159,7 @@ def mla_absorbed_decode(q_nope, q_rope, c_kv, k_rope, wk, wv, mask, *, scale):
 def leapfrog_halfstep(z, r, grad, m_inv, eps):
     """Fused momentum half-step + position full-step of velocity Verlet
     (diagonal mass).  One HBM pass under Pallas; jnp reference otherwise."""
-    if _STATE["pallas"]:
+    if pallas_enabled("leapfrog_halfstep"):
         from .leapfrog import leapfrog_halfstep as _k
         return _k(z, r, grad, m_inv, eps, interpret=_STATE["interpret"])
     from .leapfrog import leapfrog_halfstep_ref
@@ -124,7 +171,7 @@ def leapfrog_halfstep_batch(z, r, grad, m_inv, eps, kick=0.5):
     lockstep path).  ``kick=0.5`` is the classic half-kick; ``kick=1.0``
     fuses the two adjacent half-kicks between interior trajectory steps.
     One (C, D)-blocked HBM pass under Pallas; jnp reference otherwise."""
-    if _STATE["pallas"]:
+    if pallas_enabled("leapfrog_halfstep_batch"):
         from .leapfrog import leapfrog_halfstep_batch as _k
         return _k(z, r, grad, m_inv, eps, kick,
                   interpret=_STATE["interpret"])
@@ -137,7 +184,7 @@ def glm_potential_grad(x, y, w, offset=None, scale=None,
     """Fused GLM negative log-likelihood + gradient wrt ``w`` in one pass
     over the (n, d) design matrix (the logreg/CoverType potential hot
     path).  Under Pallas one HBM read of ``x`` serves value AND grad."""
-    if _STATE["pallas"]:
+    if pallas_enabled("glm_potential_grad"):
         from .glm_potential import glm_potential_grad as _k
         return _k(x, y, w, offset, scale, family,
                   interpret=_STATE["interpret"])
@@ -148,7 +195,7 @@ def mala_step(z, grad, noise, m_inv, eps):
     """Batched Langevin proposal over a (C, D) ensemble; ``grad=None``
     gives the symmetric random-walk proposal.  One (C, D)-blocked HBM
     pass under Pallas; jnp reference otherwise."""
-    if _STATE["pallas"]:
+    if pallas_enabled("mala_step"):
         from .rwm_mala import mala_step as _k
         return _k(z, grad, noise, m_inv, eps, interpret=_STATE["interpret"])
     return ref.mala_step(z, grad, noise, m_inv, eps)
@@ -158,21 +205,21 @@ def enum_contract(log_alpha, log_mat):
     """Logsumexp chain-elimination step of discrete enumeration:
     ``out[..., j] = logsumexp_i(log_alpha[..., i] + log_mat[..., i, j])``.
     One VMEM pass under Pallas; stabilized jnp reference otherwise."""
-    if _STATE["pallas"]:
+    if pallas_enabled("enum_contract"):
         from .enum_contract import enum_contract as _k
         return _k(log_alpha, log_mat, interpret=_STATE["interpret"])
     return ref.enum_contract(log_alpha, log_mat)
 
 
 def rmsnorm(x, weight, eps=1e-6):
-    if _STATE["pallas"]:
+    if pallas_enabled("rmsnorm"):
         from .rmsnorm import rmsnorm as _k
         return _k(x, weight, eps=eps, interpret=_STATE["interpret"])
     return ref.rmsnorm(x, weight, eps=eps)
 
 
 def softmax_xent(x, w_unembed, labels, *, z_loss_weight=0.0):
-    if _STATE["pallas"]:
+    if pallas_enabled("softmax_xent"):
         from .softmax_xent import softmax_xent as _k
         return _k(x, w_unembed, labels, z_loss_weight=z_loss_weight,
                   interpret=_STATE["interpret"])
@@ -180,7 +227,7 @@ def softmax_xent(x, w_unembed, labels, *, z_loss_weight=0.0):
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk, D=None, h0=None):
-    if _STATE["pallas"]:
+    if pallas_enabled("ssd_scan"):
         from .ssd_scan import ssd_scan as _k
         return _k(x, dt, A, B, C, chunk=chunk, D=D, h0=h0,
                   interpret=_STATE["interpret"])

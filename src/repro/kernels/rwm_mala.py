@@ -73,6 +73,6 @@ def mala_step(z, grad, noise, m_inv, eps, *, block=BLOCK, interpret=False):
         + [pl.BlockSpec((1, bd), lambda i: (0, i))],
         out_specs=ens_spec,
         out_shape=jax.ShapeDtypeStruct((cp, dp), z.dtype),
-        interpret=interpret,
+        interpret=interpret, name="mala_step",
     )(*operands)
     return out[:C, :D]

@@ -7,8 +7,7 @@ first init; see dryrun.py).
 from __future__ import annotations
 
 import jax
-
-from repro._compat import make_mesh_axis_kwargs as auto_axis_kwargs
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +16,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     the DCN and carries only data-parallel gradient reductions."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -25,7 +25,8 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (n // 2, 2) if n % 2 == 0 and n > 1 else (n, 1)
-    return jax.make_mesh(shape, axes, **auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_inference_mesh(num_chains, mesh_shape=None, *, devices=None):
@@ -51,7 +52,7 @@ def make_inference_mesh(num_chains, mesh_shape=None, *, devices=None):
         use = max(d for d in range(1, len(devices) + 1)
                   if num_chains % d == 0)
         return jax.make_mesh((use,), ("chains",), devices=devices[:use],
-                             **auto_axis_kwargs(1))
+                             axis_types=(AxisType.Auto,))
     chains_ax, data_ax = (int(v) for v in mesh_shape)
     if chains_ax < 1 or data_ax < 1:
         raise ReproValueError(
@@ -70,4 +71,5 @@ def make_inference_mesh(num_chains, mesh_shape=None, *, devices=None):
             f"mesh_shape={mesh_shape} needs {need} devices but only "
             f"{len(devices)} are visible.", code="RPL301")
     return jax.make_mesh((chains_ax, data_ax), ("chains", "data"),
-                         devices=devices[:need], **auto_axis_kwargs(2))
+                         devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -133,10 +133,7 @@ def _moe_local(cfg: ModelConfig, wg, wu, wd, x, logits, bias,
     k = cfg.num_experts_per_tok
     ep = 1
     for a in ep_axes:
-        if hasattr(jax.lax, "axis_size"):
-            ep *= jax.lax.axis_size(a)
-        else:  # jax < 0.4.38: psum of a literal folds to the axis size
-            ep *= jax.lax.psum(1, a)
+        ep *= jax.lax.axis_size(a)
     e_loc = e_pad // ep
 
     ids, wts, _ = _route(cfg, logits.astype(jnp.float32), bias)
@@ -236,13 +233,8 @@ def moe_apply(cfg: ModelConfig, w, x, *, capacity_factor=None):
                        all_axes=tok_axes, capacity_factor=capacity_factor)
         in_specs = (w_spec, w_spec, wd_spec, tok_spec, tok_spec, P())
         out_specs = (tok_spec, P())
-        if hasattr(jax, "shard_map"):
-            fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-        else:  # jax < 0.4.38: experimental path, check_vma spelt check_rep
-            from jax.experimental.shard_map import shard_map
-            fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         y, load = fn(w["wg"], w["wu"], w["wd"], xt, logits, bias)
 
     y = y.reshape(B, S, d)

@@ -19,14 +19,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, tempfile
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.distributed import checkpoint as ckpt
-
-from repro.launch.mesh import auto_axis_kwargs
 
 d = tempfile.mkdtemp()
 # "train" on mesh A: (data=4, model=2)
-mesh_a = jax.make_mesh((4, 2), ("data", "model"), **auto_axis_kwargs(2))
+mesh_a = jax.make_mesh((4, 2), ("data", "model"),
+                       axis_types=(AxisType.Auto,) * 2)
 w = {"emb": jnp.arange(64.0).reshape(8, 8),
      "scale": jnp.ones(8)}
 sh_a = {"emb": NamedSharding(mesh_a, P("data", "model")),
@@ -35,7 +34,8 @@ w_a = jax.tree.map(jax.device_put, w, sh_a)
 ckpt.save(w_a, d + "/ck", step=42, extra={"cursor": 7})
 
 # elastic restart on mesh B: (data=2, model=4) — different dp degree
-mesh_b = jax.make_mesh((2, 4), ("data", "model"), **auto_axis_kwargs(2))
+mesh_b = jax.make_mesh((2, 4), ("data", "model"),
+                       axis_types=(AxisType.Auto,) * 2)
 sh_b = {"emb": NamedSharding(mesh_b, P("data", "model")),
         "scale": NamedSharding(mesh_b, P("model"))}
 w_b, step, extra = ckpt.restore(w, d + "/ck", shardings=sh_b)

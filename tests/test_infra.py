@@ -111,8 +111,6 @@ def test_hlo_cost_trip_counts():
     expected = 8 * 2 * 128 * 256 * 256
     assert res["flops"] == expected, (res["flops"], expected)
     raw = compiled.cost_analysis()
-    if isinstance(raw, list):  # pre-0.4.38 jaxlib wraps it in a 1-list
-        raw = raw[0]
     assert raw["flops"] == expected / 8  # XLA counts the body once
 
 

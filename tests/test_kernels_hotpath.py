@@ -9,12 +9,14 @@ behavior, and the MALA/RWM samplers through the unchanged executor
 (posterior sanity, RPL204 contract, bit-identical checkpoint/resume).
 """
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax, random
+from jax.sharding import AbstractMesh, AxisType
 
 import repro.core as pc
 from repro.core import dist
@@ -55,6 +57,62 @@ def test_registry_signatures(spec):
                          ids=[s.name for s in ops.OP_TABLE])
 def test_registry_parity_interpret(spec):
     assert check_parity(spec, random.PRNGKey(7)).findings == []
+
+
+def test_dispatch_follows_the_platform(monkeypatch):
+    """Ops take their jnp reference on the CPU backend and their Pallas
+    kernel on a TPU; ``use_pallas`` overrides either way."""
+    z, g, noise = (random.normal(k, (4, 6))
+                   for k in random.split(random.PRNGKey(0), 3))
+
+    def routed_to_kernel():
+        # a fresh function each time: traces are cached per function, and
+        # the route is read while tracing
+        jaxpr = jax.make_jaxpr(lambda *a: ops.mala_step(*a))(
+            z, g, noise, jnp.ones(6), 0.1)
+        return "pallas_call" in str(jaxpr)
+
+    assert jax.default_backend() == "cpu"
+    assert not ops.pallas_enabled("mala_step") and not routed_to_kernel()
+    with ops.use_pallas(True, interpret=True):
+        assert ops.pallas_enabled("mala_step") and routed_to_kernel()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.pallas_enabled("mala_step")
+    # kernels the TPU compiler refuses stay on their oracle there
+    for op in ("attention", "rmsnorm", "softmax_xent", "ssd_scan"):
+        assert not ops.pallas_enabled(op)
+        with ops.use_pallas(True):
+            assert ops.pallas_enabled(op)
+    with ops.use_pallas(False):
+        assert not ops.pallas_enabled("mala_step") and not routed_to_kernel()
+    # an override may name the ops that take their kernels
+    with ops.use_pallas(["glm_potential_grad"], interpret=True):
+        assert ops.pallas_enabled("glm_potential_grad")
+        assert not ops.pallas_enabled("mala_step") and not routed_to_kernel()
+
+
+@pytest.mark.parametrize("axis_types,kernel", [
+    (None, True),                                   # no mesh in the trace
+    ((AxisType.Auto,), False),                      # partitioned program
+    ((AxisType.Explicit,), False),
+    ((AxisType.Manual,), True),                     # a shard_map body
+])
+def test_dispatch_keeps_kernels_out_of_partitioned_programs(
+        monkeypatch, axis_types, kernel):
+    """A Pallas TPU kernel cannot be partitioned: under a tracing mesh of
+    several devices the platform route takes the oracle, except along
+    manual (``shard_map``) axes; an explicit override still wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = (AbstractMesh((4,), ("data",), axis_types=axis_types)
+            if axis_types else AbstractMesh((), ()))
+    with jax.sharding.use_abstract_mesh(mesh):
+        assert ops.pallas_enabled("glm_potential_grad") is kernel
+        with ops.use_pallas(True):
+            assert ops.pallas_enabled("glm_potential_grad")
+    partial = AbstractMesh((2, 2), ("chains", "data"),
+                           axis_types=(AxisType.Auto, AxisType.Manual))
+    with jax.sharding.use_abstract_mesh(partial):
+        assert not ops.pallas_enabled("glm_potential_grad")
 
 
 # ---------------------------------------------------------------------------
@@ -212,6 +270,29 @@ def test_glm_normal_family_matches_plain():
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("family", ["bernoulli_logit", "normal"])
+@pytest.mark.parametrize("n", [40, 64, 100, 257])
+def test_glm_kernel_reads_x_in_place(family, n):
+    """Tiles span all d columns and the last tile runs past row n: interpret
+    mode fills what lies beyond with NaN, as undefined memory would be on
+    the chip, so an unmasked row would poison the value or the gradient."""
+    from repro.kernels import ref
+    from repro.kernels.glm_potential import glm_potential_grad
+    ks = random.split(random.PRNGKey(n), 4)
+    x = random.normal(ks[0], (n, 54))
+    w = 0.3 * random.normal(ks[1], (54,))
+    y = (random.bernoulli(ks[2], 0.4, (n,)).astype(jnp.float32)
+         if family == "bernoulli_logit" else random.normal(ks[2], (n,)))
+    off = random.normal(ks[3], (n,))
+    got = glm_potential_grad(x, y, w, off, 0.7, family, block_n=64,
+                             interpret=True)
+    want = ref.glm_potential_grad(x, y, w, off, 0.7, family)
+    for a, b in zip(got, want):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-4)
+
+
 def test_glm_nonaffine_predictor_falls_back_with_warning():
     """A non-affine marked site must warn and keep exact plain semantics —
     the fusion is an optimization, never a silent approximation."""
@@ -241,6 +322,23 @@ def test_glm_nonaffine_predictor_falls_back_with_warning():
     z = random.normal(random.PRNGKey(1), (3,))
     np.testing.assert_allclose(float(p_fused(z)), float(p_plain(z)),
                                rtol=1e-6)
+
+
+def test_glm_kernel_error_propagates(monkeypatch):
+    """An error raised by the kernel (or its lowering) is not a structural
+    surprise: it propagates instead of warning and quietly running the
+    plain potential, which on a TPU would hide the broken kernel."""
+    _, glm, x, y = _logreg_pair()
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile the kernel")
+
+    monkeypatch.setattr(ops, "glm_potential_grad", broken)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            initialize_model_structure(random.PRNGKey(0), glm, (x,),
+                                       {"y": y})
 
 
 def test_glm_nuts_setup_compile_once_across_arg_shapes():
