@@ -2,36 +2,16 @@
 
 ``python -m benchmarks.run``          — full paper-spec settings
 ``python -m benchmarks.run --quick``  — reduced step counts (CI / smoke)
-``--profile``                         — wrap each section in a
-                                        ``jax.profiler.trace`` (perfetto
-                                        dirs under results/profile/)
 
 Compiled programs persist in ``JAX_COMPILATION_CACHE_DIR`` when it is set,
 else in ``.jax_cache/`` at the repository root.
 """
-import contextlib
 import json
 import os
 import sys
 import time
 
 RESULTS = "benchmarks/results"
-
-
-def _profiler(enabled):
-    """Per-section ``jax.profiler.trace`` wrapper (inert when disabled)."""
-    if not enabled:
-        return lambda name: contextlib.nullcontext()
-    import jax
-
-    base = os.path.join(RESULTS, "profile")
-
-    def section(name):
-        trace_dir = os.path.join(base, name)
-        print(f"[profiling -> {trace_dir}]", flush=True)
-        return jax.profiler.trace(trace_dir)
-
-    return section
 
 
 def _previous_headlines():
@@ -92,7 +72,6 @@ def _lint_bench():
 
 def main():
     quick = "--quick" in sys.argv or os.environ.get("BENCH_QUICK") == "1"
-    profile = _profiler("--profile" in sys.argv)
     os.makedirs(RESULTS, exist_ok=True)
     t0 = time.time()
     out = {}
@@ -137,8 +116,7 @@ def main():
         print("=" * 70)
         print(title)
         print("=" * 70, flush=True)
-        with profile(key):
-            out[key] = fn(quick=quick)
+        out[key] = fn(quick=quick)
 
     out["total_wall_s"] = time.time() - t0
     if previous is not None:

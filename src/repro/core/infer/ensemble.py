@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax, random
 
+from ...obs.scopes import SCOPES, scoped
 from .hmc_util import (
     DAState,
     IntegratorState,
@@ -247,6 +248,7 @@ def _make_sample_fn(potential_fn, num_warmup, schedule, *, adapt_step_size,
         # every chain divergent (warmup's first steps): no information
         return jnp.where(jnp.isfinite(grad), grad, 0.0)
 
+    @scoped(SCOPES.adapt)
     def adapt_update(adapt: ChEESAdaptState, t, z0, z1, v1, z_next,
                      accept_prob, diverging, h) -> ChEESAdaptState:
         # 1) one dual-averaging run on the cross-chain *harmonic* mean

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ...obs.scopes import SCOPES, scoped
 from .hmc_util import (
     DAState,
     IntegratorState,
@@ -129,6 +130,7 @@ def _make_sample_fn(potential_fn, num_warmup, schedule, *, algo,
     """
     in_middle_window, window_end_is_middle = window_predicates(schedule)
 
+    @scoped(SCOPES.adapt)
     def adapt_update(state: HMCState, accept_prob) -> AdaptState:
         adapt = state.adapt_state
         t = state.i
@@ -446,6 +448,7 @@ def _cross_chain_wrap(chain_init_fn, chain_sample_fn, schedule, num_warmup,
         t = states.i[0] - 1
         at_end = window_end_is_middle(t) & (t < num_warmup)
 
+        @scoped(SCOPES.adapt)
         def refresh(states):
             adapt = states.adapt_state
             pooled = welford_pool(adapt.welford)

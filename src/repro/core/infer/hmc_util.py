@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ...obs.scopes import SCOPES, scoped
+
 
 # ---------------------------------------------------------------------------
 # integrator
@@ -59,13 +61,14 @@ def velocity_verlet(potential_fn: Callable, kinetic_grad=velocity):
     a bit-identical jnp reference elsewhere).  Dense mass matrices and
     custom ``kinetic_grad`` closures fall back to the two-pass form.
     """
-    pe_and_grad = jax.value_and_grad(potential_fn)
+    pe_and_grad = scoped(SCOPES.potential)(jax.value_and_grad(potential_fn))
     fuse_ok = kinetic_grad is velocity
 
     def init(z):
         pe, grad = pe_and_grad(z)
         return pe, grad
 
+    @scoped(SCOPES.integrator)
     def update(step_size, inverse_mass_matrix, state: IntegratorState):
         z, r, _, z_grad = state
         if fuse_ok and inverse_mass_matrix.ndim == 1:
@@ -100,8 +103,10 @@ def velocity_verlet_batch(potential_fn):
     """
     from repro.kernels import ops
 
-    pe_and_grad = chain_vmap(jax.value_and_grad(potential_fn))
+    pe_and_grad = scoped(SCOPES.potential)(
+        chain_vmap(jax.value_and_grad(potential_fn)))
 
+    @scoped(SCOPES.integrator)
     def trajectory(step_size, inverse_mass_matrix, state: IntegratorState,
                    num_steps):
         def kick_drift(s, kick):
@@ -677,6 +682,7 @@ def iterative_build_subtree(vv_update, inverse_mass_matrix, step_size,
     return tree
 
 
+@scoped(SCOPES.tree)
 def build_tree(vv_update, inverse_mass_matrix, step_size, rng_key,
                initial_state: IntegratorState, max_tree_depth=10,
                max_delta_energy=1000.0):

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax, random
 
 from ...kernels import ops
+from ...obs.scopes import SCOPES, scoped
 from .hmc_util import (
     DAState,
     WelfordState,
@@ -122,9 +123,11 @@ def _make_sample_fn(potential_fn, num_warmup, schedule, algo, *,
                     adapt_step_size, adapt_mass_matrix, target_accept_prob):
     """Pure ensemble transition ``MRWState -> MRWState``."""
     in_middle_window, window_end_is_middle = window_predicates(schedule)
-    pe_and_grad = chain_vmap(jax.value_and_grad(potential_fn))
+    pe_and_grad = scoped(SCOPES.potential)(
+        chain_vmap(jax.value_and_grad(potential_fn)))
     use_grad = algo == "MALA"
 
+    @scoped(SCOPES.adapt)
     def adapt_update(adapt: MRWAdaptState, t, z_next,
                      accept_prob) -> MRWAdaptState:
         # one dual-averaging run on the cross-chain *harmonic* mean accept

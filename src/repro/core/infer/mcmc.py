@@ -503,10 +503,11 @@ class MCMC:
 
         Telemetry rides the chunk boundary: metrics stacked by the chunk
         program come off-device in one drain, spans time each chunk (the
-        first span over a fresh program includes its compile), and the live
-        reporter prints once per chunk.  None of it touches the carry, the
-        collect path, or the checkpoint layout — ``self.telemetry = None``
-        runs the byte-identical pre-telemetry programs.
+        first span over a fresh program includes its compile) and the host
+        work after it (``chunk_drain``), and the live reporter prints once
+        per chunk.  None of it touches the carry, the collect path, or the
+        checkpoint layout — ``self.telemetry = None`` runs the
+        byte-identical pre-telemetry programs.
         """
         total = self.num_warmup + self._target_samples()
         # a convergence-gated run needs chunk boundaries to check at; an
@@ -565,42 +566,48 @@ class MCMC:
                     # close the span on finished device work, not dispatch
                     jax.block_until_ready(states)
             start, done = done, done + n
-            host_met = tele.drain_chunk(phase, start, done, met) \
-                if tele is not None else None
-            delta_div = 0
-            if count_div and out is not None and "diverging" in out:
-                if forens is not None:
-                    # the mask fetch is the same chunk-boundary sync the
-                    # plain counter pays; full positions are gathered only
-                    # for divergent draws (see obs/divergences.py)
-                    mask = jax.device_get(out["diverging"])
-                    delta_div = int(np.sum(mask))
-                    if delta_div:
-                        forens.fold(start, out, mask, phase=phase)
-                else:
-                    delta_div = int(jnp.sum(out["diverging"]))
-                self._divergences += delta_div
-                if tele is not None:
-                    tele.record_divergences(self._divergences)
-            # convergence gate: fold the drained chunk's positions into the
-            # streaming accumulators and stop between chunks once the
-            # thresholds hold.  Reads only the chunk's collect outputs —
-            # never the carry — so the draws taken are bit-identical with
-            # monitoring on or off; the one host fetch rides the chunk
-            # boundary the drain/progress/checkpoint already sync on.
-            stop = False
-            if self.monitor is not None and out is not None:
-                self.monitor.fold(jax.device_get(out["z"]))
-                stop = self.monitor.check(done - self.num_warmup)
-            if self.progress:
-                self._reporter.chunk(
-                    done=done, total=total, phase=phase,
-                    num_chains=self.num_chains,
-                    divergences=self._divergences, delta_div=delta_div,
-                    metrics=host_met if host_met is not None else out,
-                    convergence=(self.monitor.history[-1]
-                                 if self.monitor is not None
-                                 and self.monitor.history else None))
+            # the host's work at the chunk boundary: drain, divergence
+            # count and forensics, convergence fold, progress line
+            with self._span("chunk_drain", phase=phase, start=start,
+                            end=done):
+                host_met = tele.drain_chunk(phase, start, done, met) \
+                    if tele is not None else None
+                delta_div = 0
+                if count_div and out is not None and "diverging" in out:
+                    if forens is not None:
+                        # the mask fetch is the same chunk-boundary sync
+                        # the plain counter pays; full positions are
+                        # gathered only for divergent draws (see
+                        # obs/divergences.py)
+                        mask = jax.device_get(out["diverging"])
+                        delta_div = int(np.sum(mask))
+                        if delta_div:
+                            forens.fold(start, out, mask, phase=phase)
+                    else:
+                        delta_div = int(jnp.sum(out["diverging"]))
+                    self._divergences += delta_div
+                    if tele is not None:
+                        tele.record_divergences(self._divergences)
+                # convergence gate: fold the drained chunk's positions into
+                # the streaming accumulators and stop between chunks once
+                # the thresholds hold.  Reads only the chunk's collect
+                # outputs — never the carry — so the draws taken are
+                # bit-identical with monitoring on or off; the one host
+                # fetch rides the chunk boundary the drain/progress/
+                # checkpoint already sync on.
+                stop = False
+                if self.monitor is not None and out is not None:
+                    self.monitor.fold(jax.device_get(out["z"]))
+                    stop = self.monitor.check(done - self.num_warmup)
+                if self.progress:
+                    self._reporter.chunk(
+                        done=done, total=total, phase=phase,
+                        num_chains=self.num_chains,
+                        divergences=self._divergences, delta_div=delta_div,
+                        metrics=host_met if host_met is not None else out,
+                        convergence=(self.monitor.history[-1]
+                                     if self.monitor is not None
+                                     and self.monitor.history else None))
             if checkpoint_dir is not None:
                 with self._span("checkpoint_write", step=done):
                     self._save_checkpoint(

@@ -18,8 +18,11 @@ metrics on or off, and enabling them compiles one additional program per
 Public surface:
 
 - :class:`~repro.obs.telemetry.Telemetry` — the facade ``MCMC`` consumes:
-  metrics buffering, phase spans (optionally attached to
-  ``jax.profiler.trace``), counters, event sinks, run manifests.
+  metrics buffering, phase spans (each also a ``jax.profiler``
+  annotation), counters, event sinks, run manifests.
+- :data:`~repro.obs.scopes.SCOPES` — the fixed ``jax.named_scope`` names
+  of the sampler's layers (potential, integrator, tree, adapt) inside the
+  compiled programs, which a profiler trace's operations carry.
 - :class:`~repro.obs.sinks.JsonlSink` / ``MemorySink`` — event writers;
   every event validates against ``event_schema.json``
   (``python -m repro.obs.validate events.jsonl run_manifest.json``).
@@ -48,6 +51,7 @@ from .manifest import MANIFEST_NAME, RunManifest, collect_environment
 from .metrics import MetricsBuffer, metrics_struct, validate_metrics_struct
 from .monitor import Converged, ConvergenceMonitor, StreamingDiagnostics
 from .report import LiveReporter
+from .scopes import SCOPES
 from .sinks import JsonlSink, MemorySink, NullSink
 from .spans import SpanRecord
 from .telemetry import Telemetry
@@ -111,6 +115,7 @@ __all__ = [
     "MetricsBuffer",
     "NullSink",
     "RunManifest",
+    "SCOPES",
     "SpanRecord",
     "StreamingDiagnostics",
     "Telemetry",

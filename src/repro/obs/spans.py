@@ -1,7 +1,8 @@
 """Phase spans: wall-clock timing of the executor's host-side phases.
 
 A span brackets one phase of an ``MCMC.run`` — setup (model trace + lint),
-resume-restore, each compiled warmup/sample chunk, each checkpoint write —
+resume-restore, each compiled warmup/sample chunk, the host work at each
+chunk boundary (``chunk_drain``), each checkpoint write —
 entirely *outside* the compiled programs: the span clock starts before the
 chunk program is invoked and stops after its outputs are used host-side, so
 the first span over a fresh ``(setup, length)`` pair includes that
@@ -12,8 +13,9 @@ pair says *what it cost*, and no jitted callable is ever wrapped (wrapping
 would poison ``jax.eval_shape`` calls on the same programs with bogus
 timings).
 
-Optionally a span attaches ``jax.profiler.trace`` (perfetto) — see
-:meth:`repro.obs.telemetry.Telemetry.span`.
+Each span is also a ``jax.profiler.TraceAnnotation``, so a profiler
+trace taken around a run shows the phases beside the device's operations —
+see :meth:`repro.obs.telemetry.Telemetry.span`.
 """
 from __future__ import annotations
 

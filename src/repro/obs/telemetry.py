@@ -2,11 +2,11 @@
 
 Bundles the four telemetry concerns so the executor stays small: the
 metrics stream buffer (chunk-boundary drains of ``metrics_fn`` outputs),
-phase spans (with optional ``jax.profiler.trace`` attachment), event sinks
-(JSONL), and the per-run manifest.  Construction is cheap and declarative;
-all I/O is lazy until :meth:`begin_run` resolves where artifacts go
-(``dir=...`` here, else next to the run's ``checkpoint_dir``, else memory
-only).
+phase spans (also written into any active ``jax.profiler`` trace), event
+sinks (JSONL), and the per-run manifest.  Construction is cheap and
+declarative; all I/O is lazy until :meth:`begin_run` resolves where
+artifacts go (``dir=...`` here, else next to the run's
+``checkpoint_dir``, else memory only).
 
 Example::
 
@@ -31,6 +31,8 @@ import contextlib
 import os
 from typing import Optional
 
+import jax
+
 from .divergences import DivergenceRing
 from .manifest import MANIFEST_NAME, RunManifest
 from .metrics import MetricsBuffer
@@ -38,25 +40,18 @@ from .report import LiveReporter
 from .sinks import JsonlSink, MemorySink, NullSink, stamp
 from .spans import SpanClock
 
-_CHUNK_SPANS = ("warmup_chunk", "sample_chunk")
-
 
 class Telemetry:
     def __init__(self, *, metrics: bool = True, dir: Optional[str] = None,
                  sink=None, events: bool = True, manifest: bool = True,
                  reporter: Optional[LiveReporter] = None,
-                 profile_dir: Optional[str] = None,
-                 profile_spans=_CHUNK_SPANS, forensics: bool = True,
-                 forensics_capacity: int = 256):
+                 forensics: bool = True, forensics_capacity: int = 256):
         self.metrics = bool(metrics)
         self.dir = str(dir) if dir is not None else None
         self._sink_arg = sink
         self._events = bool(events)
         self._manifest_enabled = bool(manifest)
         self.reporter = reporter if reporter is not None else LiveReporter()
-        self.profile_dir = (str(profile_dir) if profile_dir is not None
-                            else None)
-        self.profile_spans = tuple(profile_spans)
         # divergence forensics: a bounded ring of divergent-transition
         # records the executor feeds at the chunk drain (positions fetched
         # only for divergent draws — a clean run pays nothing), written to
@@ -71,8 +66,6 @@ class Telemetry:
         self.spans = []
         self.counters = {}
         self._artifact_dir = None
-        self._profiling = False
-        self._span_seq = 0
 
     # -- run lifecycle ------------------------------------------------------
     def begin_run(self, run_config: dict, *, default_dir=None,
@@ -90,7 +83,6 @@ class Telemetry:
         self.buffer.clear()
         self.spans = []
         self.counters = {}
-        self._span_seq = 0
         self.forensics = (DivergenceRing(self._forensics_capacity)
                           if self._forensics_enabled else None)
         self._run_config = dict(run_config)
@@ -149,27 +141,15 @@ class Telemetry:
     def span(self, name: str, **attrs):
         """Time one host-side phase.  Yields a mutable attr dict the body
         may extend (e.g. marking a chunk cold after the compile-cache miss
-        is known); attaches ``jax.profiler.trace`` when ``profile_dir`` is
-        set and ``name`` is in ``profile_spans`` (never nested — JAX
-        supports one active trace)."""
+        is known).  The span is also a ``jax.profiler.TraceAnnotation``:
+        inside an active profiler trace it is a host event of the same
+        name, on the clock of the device's operations (with no trace
+        active it costs about a microsecond of host time)."""
         clock = SpanClock(name, attrs)
-        profiling = (self.profile_dir is not None
-                     and name in self.profile_spans and not self._profiling)
-        if profiling:
-            import jax
-            self._span_seq += 1
-            trace_dir = os.path.join(self.profile_dir,
-                                     f"{self._span_seq:04d}_{name}")
-            self._profiling = True
-            ctx = jax.profiler.trace(trace_dir)
-        else:
-            ctx = contextlib.nullcontext()
         try:
-            with ctx:
+            with jax.profiler.TraceAnnotation(name):
                 yield clock.attrs
         finally:
-            if profiling:
-                self._profiling = False
             record = clock.close()
             self.spans.append(record)
             self.sink.emit(stamp("span", record.to_event()))

@@ -382,21 +382,70 @@ def test_sequential_chain_method_rejects_telemetry():
         mcmc.run(random.PRNGKey(0), *args, **kwargs)
 
 
-def test_profile_dir_attaches_profiler_traces(tmp_path):
+def _host_events(log_dir):
+    """Names of the host events in the one profiler trace under
+    ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    plane = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    return [e.name for line in plane.lines for e in line.events]
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    import jax
     from jax import random
 
     from repro import obs
     from repro.core.infer import MCMC, NUTS
 
     model, args, kwargs = _logreg()
-    prof = tmp_path / "prof"
-    tele = obs.Telemetry(dir=str(tmp_path / "run"), profile_dir=str(prof))
+    tele = obs.Telemetry(dir=str(tmp_path / "run"))
     mcmc = MCMC(NUTS(model), num_warmup=20, num_samples=20, num_chains=2,
                 progress=False, telemetry=tele)
+    log_dir = str(tmp_path / "prof")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # annotations only, not every call
+    with jax.profiler.trace(log_dir, profiler_options=opts):
+        mcmc.run(random.PRNGKey(0), *args, checkpoint_every=10, **kwargs)
+    names = _host_events(log_dir)
+    # one host event per span, on the profiler's clock
+    assert names.count("setup") == names.count("init") == 1
+    assert names.count("warmup_chunk") == names.count("sample_chunk") == 2
+    assert names.count("chunk_drain") == 4
+    assert sorted(s.name for s in tele.spans) == sorted(
+        n for n in names if n in {s.name for s in tele.spans})
+    drains = [s for s in tele.spans if s.name == "chunk_drain"]
+    assert [(s.attr("phase"), s.attr("start"), s.attr("end"))
+            for s in drains] == [("warmup", 0, 10), ("warmup", 10, 20),
+                                 ("sample", 20, 30), ("sample", 30, 40)]
+
+
+def test_sample_chunk_carries_the_layer_scopes():
+    import re
+
+    from jax import random
+
+    from repro import obs
+    from repro.core.infer import MCMC, NUTS
+
+    model, args, kwargs = _logreg()
+    mcmc = MCMC(NUTS(model), num_warmup=5, num_samples=5, num_chains=2,
+                progress=False)
     mcmc.run(random.PRNGKey(0), *args, **kwargs)
-    traces = sorted(p.name for p in prof.iterdir())
-    assert any(t.endswith("_warmup_chunk") for t in traces)
-    assert any(t.endswith("_sample_chunk") for t in traces)
+    (prog,) = [fn for key, fn in mcmc._exec_cache.items()
+               if key[0] == "sample"]
+    text = prog.lower(mcmc.last_state).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in obs.SCOPES:
+        assert any(scope in p for p in paths), scope
+    # the gradient runs inside the leapfrog step, inside the NUTS tree
+    nested = f"{obs.SCOPES.tree}/.*{obs.SCOPES.integrator}/" \
+        f"{obs.SCOPES.potential}/"
+    assert any(re.search(nested, p) for p in paths)
 
 
 # ---------------------------------------------------------------------------
