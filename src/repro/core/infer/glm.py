@@ -1,5 +1,5 @@
 """Opt-in fused GLM potential: route a model's dominant likelihood term
-through the single-pass ``ops.glm_potential_grad`` kernel.
+through the single-pass ``ops.glm_potential_grad_slab`` kernel.
 
 A model opts in by marking its observed site::
 
@@ -17,8 +17,10 @@ the fused potential is then
 i.e. the exact prior + transform log-det through the normal machinery and
 the likelihood through the fused kernel, wrapped in ``jax.custom_vjp`` so
 the backward pass is the O(d) residual product the kernel already computed
-— instead of XLA's n-vector reverse chains.  Any structural surprise
-(non-affine or untraceable predictor, probs-parametrized Bernoulli,
+— instead of XLA's n-vector reverse chains.  X, y and the offset are laid
+out once as a lane-dense design slab, and under a chain ``vmap`` one kernel
+call serves every chain (:func:`_slab_value_and_grad`).  Any structural
+surprise (non-affine or untraceable predictor, probs-parametrized Bernoulli,
 non-constant Normal scale, site-level scale/mask, enumeration marks) falls
 back to the plain potential with a warning: the fusion is an optimization,
 never a semantics change.  An error raised by the kernel itself (or by its
@@ -126,6 +128,66 @@ def _make_sharded_nll(x, y, offset, scale, family, data_shards):
         return (ct * grad,)
 
     nll.defvjp(nll_fwd, nll_bwd)
+    return nll
+
+
+def _slab_value_and_grad(scale, family, route):
+    """``(slab, w) -> (nll, grad)`` over C coefficient rows, whose
+    ``vmap`` is one kernel call for every chain of the batch.
+
+    Under a ``vmap`` of the coefficients with the slab shared, the rule of
+    this ``jax.custom_vmap`` hands ``ops.glm_potential_grad_slab`` all the
+    rows at once, so the kernel's grid walks the n-tiles only and each slab
+    tile is read from HBM once for all chains.  A batched slab (data that
+    differs per chain) keeps one pass per chain, through the op's own
+    batching.  The rule records the route it took, and over how many
+    chains, in ``route`` while the program is traced: nothing is counted
+    per step.
+    """
+    from jax.custom_batching import custom_vmap
+
+    @custom_vmap
+    def value_and_grad(slab, w):
+        return ops.glm_potential_grad_slab(slab, w, scale, family)
+
+    @value_and_grad.def_vmap
+    def _batched(axis_size, in_batched, slab, w):
+        slab_batched, w_batched = in_batched
+        if slab_batched:
+            route.update(route="per_chain", chains=axis_size)
+            out = jax.vmap(value_and_grad.fun,
+                           in_axes=(0, 0 if w_batched else None))(slab, w)
+            return out, (True, True)
+        # the slab is shared, so the rule runs because w is batched;
+        # recorded before the call: under nested vmaps the outermost rule,
+        # which sees every chain, runs inside this call and writes last
+        route.update(route="batched", chains=axis_size * w.shape[1])
+        nll, grad = value_and_grad(slab, w.reshape(-1, w.shape[-1]))
+        return (nll.reshape(w.shape[:2]), grad.reshape(w.shape)), (True, True)
+
+    return value_and_grad
+
+
+def _make_slab_nll(slab, scale, family, route):
+    """The likelihood term over the design slab (``glm_slab``: the
+    design matrix transposed, y and the offset in its padding rows), built
+    once at setup and the only copy of the data the term keeps.  Its
+    gradient is wrapped in ``jax.custom_vjp`` with the backward pass
+    ``ct * grad``: the kernel produces the gradient in the same pass."""
+    value_and_grad = _slab_value_and_grad(scale, family, route)
+
+    def _value_and_grad(zflat):
+        nll, grad = value_and_grad(slab, zflat[None])
+        return nll[0], grad[0]
+
+    @jax.custom_vjp
+    def nll(zflat):
+        return _value_and_grad(zflat)[0]
+
+    def nll_bwd(grad, ct):
+        return (ct * grad,)
+
+    nll.defvjp(_value_and_grad, nll_bwd)
     return nll
 
 
@@ -239,20 +301,9 @@ def _fuse_glm_potential(model, model_args, model_kwargs, transforms,
                              f"split into data_shards={S} equal shards")
         nll = _make_sharded_nll(x, y, offset, scale, family, S)
     else:
-        @jax.custom_vjp
-        def nll(zflat):
-            return ops.glm_potential_grad(x, y, zflat, offset, scale,
-                                          family)[0]
-
-        def nll_fwd(zflat):
-            val, grad = ops.glm_potential_grad(x, y, zflat, offset, scale,
-                                               family)
-            return val, grad
-
-        def nll_bwd(grad, ct):
-            return (ct * grad,)
-
-        nll.defvjp(nll_fwd, nll_bwd)
+        from ...kernels.glm_potential import glm_slab
+        route = {}
+        nll = _make_slab_nll(glm_slab(x, y, offset), scale, family, route)
 
     from .util import potential_energy
     prior_model = block(model, hide=[name])
@@ -272,4 +323,8 @@ def _fuse_glm_potential(model, model_args, model_kwargs, transforms,
         # marker the setup layer / RPL204 use to tell shard-aware potentials
         # from monolithic ones (see kernel_api.KernelSetup.data_axis)
         fused_potential.data_shards = int(data_shards)
+    else:
+        # which route the chain-batched gradient took (_slab_value_and_grad);
+        # the executor reports it in the run manifest
+        fused_potential.glm_route = route
     return fused_potential

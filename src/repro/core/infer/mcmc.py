@@ -806,6 +806,11 @@ class MCMC:
                 final["convergence"] = self.monitor.decision
             if tele.metrics and setup.metrics_fn is not None:
                 final["metrics"] = tele.buffer.summary("sample")
+            route = getattr(setup.potential_fn, "glm_route", None)
+            if route:
+                # the fused GLM gradient's route, fixed when the chunk
+                # programs were traced (glm._slab_value_and_grad)
+                final["glm_route"] = dict(route)
             tele.finish_run(final)
         return self
 

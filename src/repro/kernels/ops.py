@@ -74,6 +74,9 @@ OP_TABLE = (
     OpSpec("glm_potential_grad", ("repro.kernels.glm_potential",
                                   "glm_potential_grad"),
            ("repro.kernels.ref", "glm_potential_grad"), False, 5e-3),
+    OpSpec("glm_potential_grad_slab", ("repro.kernels.glm_potential",
+                                       "glm_potential_grad_slab"),
+           ("repro.kernels.ref", "glm_potential_grad_slab"), False, 5e-3),
     OpSpec("mala_step", ("repro.kernels.rwm_mala", "mala_step"),
            ("repro.kernels.ref", "mala_step"), False, 1e-6),
     OpSpec("enum_contract", ("repro.kernels.enum_contract", "enum_contract"),
@@ -182,13 +185,26 @@ def leapfrog_halfstep_batch(z, r, grad, m_inv, eps, kick=0.5):
 def glm_potential_grad(x, y, w, offset=None, scale=None,
                        family="bernoulli_logit"):
     """Fused GLM negative log-likelihood + gradient wrt ``w`` in one pass
-    over the (n, d) design matrix (the logreg/CoverType potential hot
-    path).  Under Pallas one HBM read of ``x`` serves value AND grad."""
+    over the (n, d) design matrix (each shard of the data-sharded fused
+    potential).  Under Pallas one HBM read of ``x`` serves value AND
+    grad."""
     if pallas_enabled("glm_potential_grad"):
         from .glm_potential import glm_potential_grad as _k
         return _k(x, y, w, offset, scale, family,
                   interpret=_STATE["interpret"])
     return ref.glm_potential_grad(x, y, w, offset, scale, family)
+
+
+def glm_potential_grad_slab(slab, w, scale=None, family="bernoulli_logit"):
+    """``glm_potential_grad`` of C coefficient rows ``w`` (C, d) at once,
+    over the design slab (``glm_potential.glm_slab``: the design matrix
+    transposed, the observations and offset in its padding rows).  Under
+    Pallas one HBM read of each slab tile serves every row's value AND
+    grad."""
+    if pallas_enabled("glm_potential_grad_slab"):
+        from .glm_potential import glm_potential_grad_slab as _k
+        return _k(slab, w, scale, family, interpret=_STATE["interpret"])
+    return ref.glm_potential_grad_slab(slab, w, scale, family)
 
 
 def mala_step(z, grad, noise, m_inv, eps):

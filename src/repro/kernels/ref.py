@@ -285,6 +285,39 @@ def glm_potential_grad(x, y, w, offset=None, scale=None,
     return nll.astype(w.dtype), grad.astype(w.dtype)
 
 
+def glm_potential_grad_slab(slab, w, scale=None, family="bernoulli_logit"):
+    """``glm_potential_grad`` for C coefficient rows at once, over the
+    design slab (``repro.kernels.glm_potential.glm_slab``).
+
+    slab: (rows, n), rows ``[0, d)`` the design matrix transposed, row
+    ``d`` the observations, row ``d + 1`` the offset, the rest zeros.
+    w: (C, d).  Returns ``(nll (C,), grad (C, d))``: row ``c`` is
+    ``glm_potential_grad(x, y, w[c], offset, scale, family)``.
+    """
+    rows, _ = slab.shape
+    c, d = w.shape
+    sf = slab.astype(jnp.float32)
+    # 0 against y, 1 against the offset, 0 against the padding
+    we = jnp.concatenate([w.astype(jnp.float32), jnp.zeros((c, 1)),
+                          jnp.ones((c, 1)), jnp.zeros((c, rows - d - 2))],
+                         axis=1)
+    logits = we @ sf
+    yf = sf[d]
+    if family == "bernoulli_logit":
+        nll = jnp.sum(jax.nn.softplus(logits) - yf * logits, axis=1)
+        resid = jax.nn.sigmoid(logits) - yf
+    elif family == "normal":
+        s = jnp.asarray(scale, jnp.float32)
+        zscore = (logits - yf) / s
+        nll = jnp.sum(0.5 * zscore * zscore + jnp.log(s) + _HALF_LOG_2PI,
+                      axis=1)
+        resid = (logits - yf) / (s * s)
+    else:
+        raise ValueError(f"unknown GLM family: {family!r}")
+    grad = (resid @ sf.T)[:, :d]
+    return nll.astype(w.dtype), grad.astype(w.dtype)
+
+
 # ---------------------------------------------------------------------------
 # batched MALA / random-walk Metropolis proposal
 # ---------------------------------------------------------------------------

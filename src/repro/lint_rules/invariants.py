@@ -82,6 +82,14 @@ def _sample_inputs(name, key):
         y = (random.uniform(ks[2], (n,)) < 0.5).astype(jnp.float32)
         offset = random.normal(ks[3], (n,)) * 0.1
         return (x, y, w, offset), {"family": "bernoulli_logit"}
+    if name == "glm_potential_grad_slab":
+        from ..kernels.glm_potential import glm_slab
+        n, c, d = 300, 3, 7  # n not a multiple of the 128-lane tile
+        x = random.normal(ks[0], (n, d))
+        w = random.normal(ks[1], (c, d)) * 0.3
+        y = (random.uniform(ks[2], (n,)) < 0.5).astype(jnp.float32)
+        offset = random.normal(ks[3], (n,)) * 0.1
+        return (glm_slab(x, y, offset), w), {"family": "bernoulli_logit"}
     if name == "mala_step":
         c, d = 5, 515
         z, g, noise = (random.normal(k, (c, d)) for k in ks[:3])

@@ -293,6 +293,124 @@ def test_glm_kernel_reads_x_in_place(family, n):
                                    atol=1e-4)
 
 
+def _glm_slab_data(n, d, family, with_offset, key):
+    ks = random.split(key, 3)
+    x = random.normal(ks[0], (n, d))
+    y = (random.bernoulli(ks[1], 0.4, (n,)).astype(jnp.float32)
+         if family == "bernoulli_logit" else random.normal(ks[1], (n,)))
+    off = random.normal(ks[2], (n,)) if with_offset else None
+    return x, y, off
+
+
+@pytest.mark.parametrize("family", ["bernoulli_logit", "normal"])
+@pytest.mark.parametrize("chains", [1, 4, 64])
+@pytest.mark.parametrize("with_offset", [False, True])
+def test_glm_slab_kernel_matches_per_chain_ref(family, chains, with_offset):
+    """The chain-batched kernel over the design slab gives every chain the
+    per-chain oracle's value and gradient.  n = 300 is no multiple of the
+    128-lane tile: interpret mode fills the last tile's overrun with NaN,
+    as undefined memory would be on the chip."""
+    from repro.kernels import ref
+    from repro.kernels.glm_potential import glm_potential_grad_slab, glm_slab
+    n, d = 300, 54
+    x, y, off = _glm_slab_data(n, d, family, with_offset,
+                               random.PRNGKey(chains))
+    w = 0.3 * random.normal(random.PRNGKey(1), (chains, d))
+    got = glm_potential_grad_slab(glm_slab(x, y, off), w, 0.7, family,
+                                  block_n=128, interpret=True)
+    want = jax.vmap(lambda wc: ref.glm_potential_grad(x, y, wc, off, 0.7,
+                                                      family))(w)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-4)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr`` and its sub-jaxprs."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("family", ["bernoulli_logit", "normal"])
+def test_glm_chain_batched_gradient_is_one_kernel_call(family):
+    """``vmap(value_and_grad(fused))`` over 4 chains is one kernel call
+    whose grid walks the n-tiles only, with no chain axis, and the fused
+    potential's route reads batched over 4 chains."""
+    n, d = 300, 5
+    x, y, _ = _glm_slab_data(n, d, family, False, random.PRNGKey(3))
+
+    def model(x, y=None):
+        w = pc.sample("w", dist.Normal(jnp.zeros(d),
+                                       jnp.ones(d)).to_event(1))
+        fn = (dist.Bernoulli(logits=x @ w) if family == "bernoulli_logit"
+              else dist.Normal(x @ w, 0.7).to_event(1))
+        return pc.sample("y", fn, obs=y, infer={"potential": "glm"})
+
+    zs = random.normal(random.PRNGKey(4), (4, d))
+    with ops.use_pallas(True, interpret=True):
+        fused = initialize_model_structure(random.PRNGKey(0), model, (x,),
+                                           {"y": y})[0]
+        jaxpr = jax.make_jaxpr(jax.vmap(jax.value_and_grad(fused)))(zs)
+    calls = _pallas_calls(jaxpr.jaxpr)
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].grid == (1,)   # one n-tile
+    assert fused.glm_route == {"route": "batched", "chains": 4}
+
+
+@pytest.mark.parametrize("w_batched", [False, True])
+def test_glm_batched_data_keeps_one_pass_per_chain(w_batched):
+    """Data that differs per chain (a batched slab) takes the per-chain
+    route: the kernel's own batching, one grid row per chain, with the
+    per-chain oracle's numbers."""
+    from repro.core.infer.glm import _slab_value_and_grad
+    from repro.kernels import ref
+    from repro.kernels.glm_potential import glm_slab
+    n, d, chains = 300, 6, 3
+    data = [_glm_slab_data(n, d, "bernoulli_logit", True, random.PRNGKey(k))
+            for k in range(chains)]
+    slabs = jnp.stack([glm_slab(*xyo) for xyo in data])
+    w = 0.3 * random.normal(random.PRNGKey(9), (chains, 1, d))
+    route = {}
+    vg = _slab_value_and_grad(None, "bernoulli_logit", route)
+    batched = jax.vmap(vg, in_axes=(0, 0 if w_batched else None))
+    with ops.use_pallas(True, interpret=True):
+        args = (slabs, w if w_batched else w[0])
+        calls = _pallas_calls(jax.make_jaxpr(batched)(*args).jaxpr)
+        nll, grad = batched(*args)
+    assert route == {"route": "per_chain", "chains": chains}
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].grid[0] == chains
+    for c, (x, y, off) in enumerate(data):
+        v, g = ref.glm_potential_grad(x, y, w[c if w_batched else 0, 0],
+                                      off)
+        np.testing.assert_allclose(float(nll[c, 0]), float(v), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(grad[c, 0]), np.asarray(g),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_glm_nuts_reports_its_route_in_the_manifest(tmp_path):
+    """Four vectorized NUTS chains take the chain-batched route, and the
+    run manifest says so."""
+    import json
+
+    from repro import obs
+    _, glm, x, y = _logreg_pair(n=200, d=3)
+    tele = obs.Telemetry(dir=str(tmp_path))
+    mcmc = MCMC(NUTS(glm), num_warmup=20, num_samples=20, num_chains=4,
+                progress=False, telemetry=tele)
+    mcmc.run(random.PRNGKey(0), x, y=y)
+    with open(tmp_path / "run_manifest.json") as f:
+        final = json.load(f)["sessions"][-1]["final"]
+    assert final["glm_route"] == {"route": "batched", "chains": 4}
+
+
 def test_glm_nonaffine_predictor_falls_back_with_warning():
     """A non-affine marked site must warn and keep exact plain semantics —
     the fusion is an optimization, never a silent approximation."""
@@ -333,7 +451,7 @@ def test_glm_kernel_error_propagates(monkeypatch):
     def broken(*args, **kwargs):
         raise RuntimeError("Mosaic failed to compile the kernel")
 
-    monkeypatch.setattr(ops, "glm_potential_grad", broken)
+    monkeypatch.setattr(ops, "glm_potential_grad_slab", broken)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         with pytest.raises(RuntimeError, match="Mosaic"):

@@ -7,19 +7,27 @@ the Mosaic compiler refuses, VMEM overuse, and kernels that fail to lower.
 The topology is described inside a module fixture, so importing this file
 never loads libtpu, and every worker collects the same tests.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.infer.glm import _make_slab_nll
+from repro.kernels import ops
 from repro.kernels.enum_contract import enum_contract
-from repro.kernels.glm_potential import glm_potential_grad
+from repro.kernels.glm_potential import (
+    glm_potential_grad,
+    glm_potential_grad_slab,
+)
 from repro.kernels.leapfrog import leapfrog_halfstep, leapfrog_halfstep_batch
 from repro.kernels.rwm_mala import mala_step
 
 N, D = 581_012, 54      # CoverType width (paper Table 2a)
+ROWS = 56               # the design slab's rows: round_up(D + 2, 8)
 CHAINS = 4              # NUTS chains
 ENSEMBLE = 64           # ChEES / MALA chains
 
@@ -61,6 +69,47 @@ def test_glm_potential_grad_vmapped_over_chains_compiles(one_chip):
 
     _assert_kernel(chains, _spec(one_chip, N, D), _spec(one_chip, N),
                    _spec(one_chip, CHAINS, D), name="glm_potential_grad")
+
+
+@pytest.mark.parametrize("chains", [CHAINS, ENSEMBLE])
+def test_glm_potential_grad_slab_compiles(one_chip, chains):
+    _assert_kernel(glm_potential_grad_slab, _spec(one_chip, ROWS, N),
+                   _spec(one_chip, chains, D), name="glm_potential_grad")
+
+
+def _elements(line):
+    """The most elements of any array shape written in an HLO line."""
+    return max((math.prod(int(n) for n in dims.split(",") if n)
+                for dims in re.findall(r"[a-z]+\d*\[([\d,]*)\]", line)),
+               default=0)
+
+
+@pytest.mark.parametrize("chains", [CHAINS, ENSEMBLE])
+def test_glm_fused_gradient_reads_the_slab_in_place(one_chip, chains):
+    """The fused likelihood's gradient, vmapped over chains as the executor
+    runs it, compiles to one kernel call that takes the slab where it
+    lies: no other instruction touches an array of n or more elements (a
+    reshape, copy, pad or transpose of the data on every call)."""
+    route = {}
+
+    def over_chains(slab, w):
+        nll = _make_slab_nll(slab, None, "bernoulli_logit", route)
+        return jax.vmap(jax.value_and_grad(nll))(w)
+
+    with ops.use_pallas(True):
+        text = jax.jit(over_chains).lower(
+            _spec(one_chip, ROWS, N),
+            _spec(one_chip, chains, D)).compile().as_text()
+    lines = [line.strip() for line in text.splitlines()
+             if re.match(r"\s*(ROOT )?%\S+ = ", line)]
+    kernels = [line for line in lines
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "glm_potential_grad" in kernels[0]
+    big = [line[:160] for line in lines
+           if line not in kernels and " parameter(" not in line
+           and _elements(line) >= N]
+    assert not big, big
+    assert route == {"route": "batched", "chains": chains}
 
 
 def test_leapfrog_halfstep_compiles(one_chip):
