@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.special import betaln, gammaln
 
-from . import constraints
+from . import constraints, fmath
 from .distribution import Distribution, ExpandedDistribution
 
 _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)
@@ -47,7 +47,7 @@ class Normal(Distribution):
 
     def log_prob(self, value):
         z = (value - self.loc) / self.scale
-        return -0.5 * z * z - jnp.log(self.scale) - _HALF_LOG_2PI
+        return -0.5 * z * z - fmath.log(self.scale) - _HALF_LOG_2PI
 
 
 class LogNormal(Distribution):
@@ -61,12 +61,12 @@ class LogNormal(Distribution):
 
     def sample(self, rng_key=None, sample_shape=()):
         eps = jax.random.normal(rng_key, self.shape(sample_shape))
-        return jnp.exp(self.loc + self.scale * eps)
+        return fmath.exp(self.loc + self.scale * eps)
 
     def log_prob(self, value):
-        log_value = jnp.log(value)
+        log_value = fmath.log(value)
         z = (log_value - self.loc) / self.scale
-        return (-0.5 * z * z - jnp.log(self.scale) - _HALF_LOG_2PI
+        return (-0.5 * z * z - fmath.log(self.scale) - _HALF_LOG_2PI
                 - log_value)
 
 
@@ -85,7 +85,7 @@ class Cauchy(Distribution):
 
     def log_prob(self, value):
         z = (value - self.loc) / self.scale
-        return -math.log(math.pi) - jnp.log(self.scale) - jnp.log1p(z * z)
+        return -math.log(math.pi) - fmath.log(self.scale) - fmath.log1p(z * z)
 
 
 class StudentT(Distribution):
@@ -114,8 +114,8 @@ class StudentT(Distribution):
         df = self.df
         z = (value - self.loc) / self.scale
         return (gammaln((df + 1.0) / 2.0) - gammaln(df / 2.0)
-                - 0.5 * jnp.log(df * math.pi) - jnp.log(self.scale)
-                - 0.5 * (df + 1.0) * jnp.log1p(z * z / df))
+                - 0.5 * fmath.log(df * math.pi) - fmath.log(self.scale)
+                - 0.5 * (df + 1.0) * fmath.log1p(z * z / df))
 
 
 class Gamma(Distribution):
@@ -137,7 +137,7 @@ class Gamma(Distribution):
 
     def log_prob(self, value):
         conc = self.concentration
-        return (conc * jnp.log(self.rate) + (conc - 1.0) * jnp.log(value)
+        return (conc * fmath.log(self.rate) + (conc - 1.0) * fmath.log(value)
                 - self.rate * value - gammaln(conc))
 
 
@@ -164,7 +164,7 @@ class InverseGamma(Distribution):
 
     def log_prob(self, value):
         conc = self.concentration
-        return (conc * jnp.log(self.rate) - (conc + 1.0) * jnp.log(value)
+        return (conc * fmath.log(self.rate) - (conc + 1.0) * fmath.log(value)
                 - self.rate / value - gammaln(conc))
 
 
@@ -187,7 +187,7 @@ class Beta(Distribution):
 
     def log_prob(self, value):
         a, b = self.concentration1, self.concentration0
-        return ((a - 1.0) * jnp.log(value) + (b - 1.0) * jnp.log1p(-value)
+        return ((a - 1.0) * fmath.log(value) + (b - 1.0) * fmath.log1p(-value)
                 - betaln(a, b))
 
 
@@ -204,7 +204,7 @@ class Exponential(Distribution):
         return jnp.clip(std, _tiny(std)) / self.rate
 
     def log_prob(self, value):
-        return jnp.log(self.rate) - self.rate * value
+        return fmath.log(self.rate) - self.rate * value
 
 
 class HalfNormal(Distribution):
@@ -222,7 +222,7 @@ class HalfNormal(Distribution):
 
     def log_prob(self, value):
         z = value / self.scale
-        return (math.log(2.0) - 0.5 * z * z - jnp.log(self.scale)
+        return (math.log(2.0) - 0.5 * z * z - fmath.log(self.scale)
                 - _HALF_LOG_2PI)
 
 
@@ -241,8 +241,8 @@ class HalfCauchy(Distribution):
 
     def log_prob(self, value):
         z = value / self.scale
-        return (math.log(2.0 / math.pi) - jnp.log(self.scale)
-                - jnp.log1p(z * z))
+        return (math.log(2.0 / math.pi) - fmath.log(self.scale)
+                - fmath.log1p(z * z))
 
 
 class Dirichlet(Distribution):
@@ -266,7 +266,7 @@ class Dirichlet(Distribution):
         conc = self.concentration
         normalizer = gammaln(jnp.sum(conc, axis=-1)) - jnp.sum(
             gammaln(conc), axis=-1)
-        return jnp.sum((conc - 1.0) * jnp.log(value), axis=-1) + normalizer
+        return jnp.sum((conc - 1.0) * fmath.log(value), axis=-1) + normalizer
 
 
 class MultivariateNormal(Distribution):
@@ -312,7 +312,7 @@ class MultivariateNormal(Distribution):
         m = jax.scipy.linalg.solve_triangular(tril, diff[..., None],
                                               lower=True)[..., 0]
         half_log_det = jnp.sum(
-            jnp.log(jnp.diagonal(tril, axis1=-2, axis2=-1)), axis=-1)
+            fmath.log(jnp.diagonal(tril, axis1=-2, axis2=-1)), axis=-1)
         dim = self.event_shape[0]
         return (-0.5 * jnp.sum(m * m, axis=-1) - half_log_det
                 - dim * _HALF_LOG_2PI)

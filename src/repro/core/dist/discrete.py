@@ -1,20 +1,20 @@
 """Discrete distributions (Bernoulli, Categorical, DiscreteUniform).
 
 ``Bernoulli``/``Categorical`` accept either ``probs`` or ``logits`` (exactly
-one) and compute ``log_prob`` natively in logit space — the ``logits``
-parameterization never round-trips through probabilities, so densities stay
-finite for extreme logits.  All three have finite supports and implement
-``enumerate_support``, which is what lets the enumeration subsystem
-(:mod:`repro.core.infer.enum`) marginalize them exactly instead of requiring
-a ``biject_to`` bijection: use them as observed sites, or leave them latent
-and let ``log_density``/NUTS sum them out.
+one) and compute ``log_prob`` in the parameterization given — ``logits``
+never round-trip through probabilities, so densities stay finite for extreme
+logits, and ``probs`` never through logits.  All three have finite supports
+and implement ``enumerate_support``, which is what lets the enumeration
+subsystem (:mod:`repro.core.infer.enum`) marginalize them exactly instead of
+requiring a ``biject_to`` bijection: use them as observed sites, or leave them
+latent and let ``log_density``/NUTS sum them out.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from . import constraints
+from . import constraints, fmath
 from .distribution import Distribution
 
 
@@ -47,16 +47,10 @@ class Bernoulli(Distribution):
         param = probs if probs is not None else logits
         super().__init__(jnp.shape(param))
 
-    def _logits(self):
-        if self.logits is not None:
-            return self.logits
-        p = _clip_probs(self.probs)
-        return jnp.log(p) - jnp.log1p(-p)
-
     def _probs(self):
         if self.probs is not None:
             return self.probs
-        return jax.nn.sigmoid(self.logits)
+        return fmath.sigmoid(self.logits)
 
     def sample(self, rng_key=None, sample_shape=()):
         draws = jax.random.bernoulli(rng_key, self._probs(),
@@ -64,8 +58,13 @@ class Bernoulli(Distribution):
         return draws.astype(jnp.int32)
 
     def log_prob(self, value):
-        logits = self._logits()
-        return value * logits - jax.nn.softplus(logits)
+        if self.logits is not None:
+            return value * self.logits - fmath.softplus(self.logits)
+        # one multiply-add a value over tables of the parameters' shape,
+        # as the logits form costs
+        p = _clip_probs(self.probs)
+        log_1mp = fmath.log1p(-p)
+        return value * (fmath.log(p) - log_1mp) + log_1mp
 
     def enumerate_support(self, expand=True):
         return _enum_values(2, self.batch_shape, expand)
@@ -95,14 +94,14 @@ class Categorical(Distribution):
     def _logits(self):
         if self.logits is not None:
             return self.logits
-        return jnp.log(_clip_probs(self.probs))
+        return fmath.log(_clip_probs(self.probs))
 
     def sample(self, rng_key=None, sample_shape=()):
         return jax.random.categorical(rng_key, self._logits(),
                                       shape=self.shape(sample_shape))
 
     def log_prob(self, value):
-        log_pmf = jax.nn.log_softmax(self._logits(), axis=-1)
+        log_pmf = fmath.log_softmax(self._logits(), axis=-1)
         value = jnp.asarray(value, jnp.int32)
         batch = jnp.broadcast_shapes(jnp.shape(value), self.batch_shape)
         log_pmf = jnp.broadcast_to(log_pmf, batch + (self._num_categories,))
@@ -145,7 +144,7 @@ class DiscreteUniform(Distribution):
     def log_prob(self, value):
         in_support = self.support(value)
         n = self.high - self.low + 1
-        lp = jnp.full(jnp.shape(value), -jnp.log(float(n)))
+        lp = jnp.full(jnp.shape(value), -fmath.log(float(n)))
         return jnp.where(in_support, lp, -jnp.inf)
 
     def enumerate_support(self, expand=True):

@@ -16,10 +16,9 @@ from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
 
-from . import constraints
+from . import constraints, fmath
 
 __all__ = [
     "Transform",
@@ -83,38 +82,52 @@ class AffineTransform(Transform):
         return (y - self.loc) / self.scale
 
     def log_abs_det_jacobian(self, x, y):
-        return jnp.broadcast_to(jnp.log(jnp.abs(self.scale)), jnp.shape(x))
+        return jnp.broadcast_to(fmath.log(jnp.abs(self.scale)), jnp.shape(x))
 
 
 class ExpTransform(Transform):
     codomain = constraints.positive
 
     def __call__(self, x):
-        return jnp.exp(x)
+        return fmath.exp(x)
 
     def inv(self, y):
-        return jnp.log(y)
+        return fmath.log(y)
 
     def log_abs_det_jacobian(self, x, y):
         return x
+
+
+def _clipped_sigmoid(x):
+    """``sigmoid(x)`` kept inside ``[tiny, 1 - eps]`` of its dtype, as
+    NumPyro's transforms keep it.  Past ``x < -87`` f32 underflows to 0
+    (the TPU flushes subnormals), and a density with a ``log(p)`` term
+    whose factor is negative (a Beta or Dirichlet concentration below 1)
+    would read +inf there: a hole the sampler falls into.  The log
+    Jacobian stays the exact one of the unclipped map, so the potential
+    still rises past the clip."""
+    finfo = jnp.finfo(jnp.result_type(x, jnp.float32))
+    return jnp.clip(fmath.sigmoid(x), finfo.tiny, 1.0 - finfo.eps)
 
 
 class SigmoidTransform(Transform):
     codomain = constraints.unit_interval
 
     def __call__(self, x):
-        return jax.nn.sigmoid(x)
+        return _clipped_sigmoid(x)
 
     def inv(self, y):
-        return jnp.log(y) - jnp.log1p(-y)
+        return fmath.log(y) - fmath.log1p(-y)
 
     def log_abs_det_jacobian(self, x, y):
         # log sigma(x) + log sigma(-x)
-        return -jax.nn.softplus(x) - jax.nn.softplus(-x)
+        return -fmath.softplus(x) - fmath.softplus(-x)
 
 
 class IntervalTransform(Transform):
-    """u -> lower + (upper - lower) * sigmoid(u)."""
+    """u -> lower + (upper - lower) * sigmoid(u), the sigmoid held off 0
+    and 1 as :class:`SigmoidTransform` holds it (``unit_interval`` maps
+    here)."""
 
     def __init__(self, lower_bound=0.0, upper_bound=1.0):
         self.lower_bound = lower_bound
@@ -123,22 +136,24 @@ class IntervalTransform(Transform):
 
     def __call__(self, x):
         width = self.upper_bound - self.lower_bound
-        return self.lower_bound + width * jax.nn.sigmoid(x)
+        return self.lower_bound + width * _clipped_sigmoid(x)
 
     def inv(self, y):
         z = (y - self.lower_bound) / (self.upper_bound - self.lower_bound)
-        return jnp.log(z) - jnp.log1p(-z)
+        return fmath.log(z) - fmath.log1p(-z)
 
     def log_abs_det_jacobian(self, x, y):
         width = self.upper_bound - self.lower_bound
-        return jnp.log(width) - jax.nn.softplus(x) - jax.nn.softplus(-x)
+        return fmath.log(width) - fmath.softplus(x) - fmath.softplus(-x)
 
 
 class StickBreakingTransform(Transform):
     """R^{K-1} -> K-simplex via the stick-breaking construction (Stan 10.7).
 
     ``z_k = sigmoid(u_k - log(K - k - 1))`` (0-indexed offset keeps u = 0 at
-    the uniform simplex point), ``y_k = z_k * prod_{i<k}(1 - z_i)``.
+    the uniform simplex point), ``y_k = z_k * prod_{i<k}(1 - z_i)``.  As in
+    :class:`SigmoidTransform`, ``z`` and ``y`` are kept at or above the
+    dtype's smallest normal number, so no coordinate is an exact 0.
     """
 
     codomain = constraints.simplex
@@ -147,15 +162,22 @@ class StickBreakingTransform(Transform):
         # [size, ..., 1] from an iota, not from a host array: jax 0.9 cannot
         # pass a host-array constant to a program as an argument
         # (JAX_USE_SIMPLIFIED_JAXPR_CONSTANTS=1)
-        return jnp.log(size - jnp.arange(size, dtype=jnp.float32))
+        return fmath.log(size - jnp.arange(size, dtype=jnp.float32))
 
     def __call__(self, x):
-        z = jax.nn.sigmoid(x - self._offset(x.shape[-1]))
-        z1m_cumprod = jnp.cumprod(1.0 - z, axis=-1)
+        xo = x - self._offset(x.shape[-1])
+        z = _clipped_sigmoid(xo)
+        # 1 - z from -xo: ``1.0 - z`` keeps a few digits at best where z is
+        # within a few ulps of 1, and the rest of the stick is then off by
+        # up to tens of percent
+        finfo = jnp.finfo(jnp.result_type(x, jnp.float32))
+        z1m = jnp.maximum(fmath.sigmoid(-xo), finfo.eps)
+        z1m_cumprod = jnp.cumprod(z1m, axis=-1)
         pad_shape = x.shape[:-1] + (1,)
         lead = jnp.concatenate(
             [jnp.ones(pad_shape, x.dtype), z1m_cumprod[..., :-1]], axis=-1)
-        return jnp.concatenate([z * lead, z1m_cumprod[..., -1:]], axis=-1)
+        y = jnp.concatenate([z * lead, z1m_cumprod[..., -1:]], axis=-1)
+        return jnp.maximum(y, jnp.finfo(y.dtype).tiny)
 
     def inv(self, y):
         # remainder before stick k: 1 - sum_{i<k} y_i
@@ -164,18 +186,18 @@ class StickBreakingTransform(Transform):
         remainder = jnp.concatenate(
             [jnp.ones(pad_shape, y.dtype), 1.0 - cs[..., :-1]], axis=-1)
         z = jnp.clip(y[..., :-1] / remainder, 1e-30, 1.0 - 1e-7)
-        u = jnp.log(z) - jnp.log1p(-z)
+        u = fmath.log(z) - fmath.log1p(-z)
         return u + self._offset(u.shape[-1])
 
     def log_abs_det_jacobian(self, x, y):
         xo = x - self._offset(x.shape[-1])
-        cs = jnp.cumsum(y[..., :-1], axis=-1)
-        pad_shape = y.shape[:-1] + (1,)
-        remainder = jnp.concatenate(
-            [jnp.ones(pad_shape, y.dtype), 1.0 - cs[..., :-1]], axis=-1)
-        # dy_k/du_k = z_k (1 - z_k) * remainder_k, triangular Jacobian
-        elem = (-jax.nn.softplus(xo) - jax.nn.softplus(-xo)
-                + jnp.log(jnp.clip(remainder, 1e-30)))
+        # dy_k/du_k = z_k (1 - z_k) * remainder_k, triangular Jacobian; the
+        # log remainder before stick k, sum_{i<k} log(1 - z_i), is summed
+        # from x: 1 - cumsum(y) would cancel where the sticks take nearly
+        # everything
+        log_1mz = -fmath.softplus(xo)
+        log_remainder = jnp.cumsum(log_1mz, axis=-1) - log_1mz
+        elem = -fmath.softplus(-xo) + log_1mz + log_remainder
         return jnp.sum(elem, axis=-1)
 
 
@@ -201,14 +223,14 @@ class LowerCholeskyTransform(Transform):
         idx = jnp.tril_indices(d, -1)
         m = jnp.zeros(x.shape[:-1] + (d, d), x.dtype)
         m = m.at[..., idx[0], idx[1]].set(x[..., : d * (d - 1) // 2])
-        diag = jnp.exp(x[..., d * (d - 1) // 2:])
+        diag = fmath.exp(x[..., d * (d - 1) // 2:])
         return m.at[..., jnp.arange(d), jnp.arange(d)].set(diag)
 
     def inv(self, y):
         d = y.shape[-1]
         idx = jnp.tril_indices(d, -1)
         offdiag = y[..., idx[0], idx[1]]
-        log_diag = jnp.log(jnp.diagonal(y, axis1=-2, axis2=-1))
+        log_diag = fmath.log(jnp.diagonal(y, axis1=-2, axis2=-1))
         return jnp.concatenate([offdiag, log_diag], axis=-1)
 
     def log_abs_det_jacobian(self, x, y):

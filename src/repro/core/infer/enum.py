@@ -15,10 +15,12 @@ with handlers and broadcasting:
   out by variable elimination in log space — plate dims stay independent
   products, exactly as without enumeration.
 - :func:`markov` is the sequential counterpart for chain-structured models:
-  it eliminates the state along the time axis inside ``lax.scan`` at
-  O(T·K²) — instead of the O(K^T) a parallel dim per step would cost — with
-  the hot logsumexp contraction dispatched through
-  :func:`repro.kernels.ops.enum_contract` (Pallas kernel / bit-parity ref).
+  it eliminates the state along the time axis at O(T·K²) — instead of the
+  O(K^T) a parallel dim per step would cost — for every row of the plates
+  around it at once, through :func:`repro.kernels.ops.enum_contract_chain`
+  (the whole forward algorithm in one Pallas call and its gradient in one
+  more; a ``lax.scan`` of :func:`repro.kernels.ops.enum_contract` steps on
+  the reference path).
 - :func:`infer_discrete` recovers the *posterior* of the marginalized sites
   given continuous draws: forward-filter/backward-sample for ``markov``
   chains, exact sequential conditioning on the joint enumeration tensor for
@@ -32,6 +34,7 @@ marginalizes the discrete ones.  See ``docs/enumeration.md``.
 """
 from __future__ import annotations
 
+import math
 import warnings
 from collections import OrderedDict
 from typing import Optional
@@ -40,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax, random
 
+from ...obs.scopes import SCOPES
 from .. import dist as _dist
 from .. import primitives
 from ..errors import ReproNotImplementedError, ReproValueError
@@ -445,46 +449,124 @@ def _find_enum_state():
     return None
 
 
-def _assert_no_active_plates(what: str) -> None:
-    for handler in primitives.stack():
-        if isinstance(handler, _plate) and handler._frame is not None:
-            raise ReproNotImplementedError(
-                f"{what} inside an active plate is not supported; vmap the "
-                "whole model over the batch of sequences instead",
-                code="RPL014", site=handler.name)
+def _enclosing_plates():
+    """The plates open around a ``markov`` call, outermost first."""
+    return [h for h in primitives.stack()
+            if isinstance(h, _plate) and h._frame is not None]
 
 
-def _step_factor(tr, plate_budget: int, dims):
-    """Collapse one markov step's local trace into a factor over ``dims``
-    (ascending, i.e. prev before cur).
+class _StateOnRows(Messenger):
+    """RPL014 for a state site inside a plate opened within the transition:
+    summing that plate before the elimination would make all its elements
+    share one state."""
 
-    Within-step plate dims (the rightmost ``plate_budget`` axes) are summed —
-    conditionally independent given the state — so the factor's only axes are
-    the chain's enumeration dims; any other enumeration dim leaking in (a
-    transition depending on a separately enumerated site) is a loud error.
+    def __init__(self, row_dims, chain: str):
+        super().__init__()
+        self.row_dims = frozenset(row_dims)
+        self.chain = chain
+
+    def process_message(self, msg: dict) -> None:
+        if not (msg["type"] == "sample" and not msg["is_observed"]
+                and msg["value"] is None
+                and getattr(msg["fn"], "has_enumerate_support", False)):
+            return
+        for frame in msg["cond_indep_stack"]:
+            if frame.dim not in self.row_dims:
+                raise ReproNotImplementedError(
+                    f"markov '{self.chain}': state site '{msg['name']}' lies "
+                    f"inside plate '{frame.name}', opened within the "
+                    "transition, where all its elements would share one "
+                    "state; open the plate around the markov call instead "
+                    "(one chain per element) and keep only observed sites "
+                    "in plates opened inside the transition",
+                    code="RPL014", site=frame.name)
+
+
+def _step_factors(tr, plate_budget: int, dims, rows, K: int, state: str):
+    """Collapse one markov step's local trace into ``(unary, pair, keep)``.
+
+    ``dims`` are the chain's enumeration dims (ascending: prev before
+    cur), ``rows`` the ``(dim, size)`` of the plates around the call: each
+    of their elements is a row, B in all (1 without any).  Plate dims
+    opened within the step are summed, conditionally independent given
+    the state; any other enumeration dim leaking in (a transition
+    depending on a separately enumerated site) is a loud error.
+
+    - ``unary`` (B, K): the sites that do not read the previous state
+      (the emissions), each with its own mask;
+    - ``pair``: the sites that do (the transition), ``(K, K)`` when no
+      row varies them, else ``(B, K, K)``; None at the first step;
+    - ``keep`` (B,): the state site's mask, all True without one.  From
+      the second step on, a row whose state is masked leaves the step out
+      (the caller holds its forward message), so the state's own factor
+      is taken unmasked; at the first step a masked state scores the
+      normalized ``-log K``, which sums to 0.
     """
-    nd = -min(dims) - plate_budget
-    acc = jnp.zeros((1,) * nd)
+    R = -min(dims)
+    row_axes = [R + d for d, _ in rows]
+    enum_axes = [R + d for d in dims]
+    inner = [R + p for p in range(-plate_budget, 0)
+             if R + p not in row_axes]
+    B = math.prod(n for _, n in rows)
+
+    def on_rows(x, axes, sizes):
+        """``x`` broadcast to ``sizes`` at ``axes`` and moved there, in
+        that order, all other axes dropped (they have extent 1)."""
+        shape = [1] * R
+        for a, n in zip(axes, sizes):
+            shape[a] = n
+        x = jnp.broadcast_to(jnp.reshape(x, (1,) * (R - jnp.ndim(x))
+                                         + jnp.shape(x)), shape)
+        x = jnp.transpose(x, list(axes)
+                          + [a for a in range(R) if a not in axes])
+        return x.reshape(tuple(sizes))
+
+    unary = pair = jnp.zeros((1,) * R)
+    keep = None
     for site in tr.values():
         if site["type"] != "sample":
             continue
-        lp = _site_log_prob(site)
-        if jnp.ndim(lp) > plate_budget:
-            if plate_budget:
-                lp = jnp.sum(lp, axis=tuple(range(-plate_budget, 0)))
+        if site["name"] == state:
+            keep = site["mask"]
+        if site["name"] == state and len(dims) == 2:
+            lp = site["fn"].log_prob(site["value"])
+            if site["scale"] is not None:
+                lp = lp * site["scale"]
         else:
-            lp = jnp.sum(lp)
-        lp = jnp.reshape(lp, (1,) * (nd - jnp.ndim(lp)) + jnp.shape(lp))
-        for ax in range(nd):
-            orig_dim = (ax - nd) - plate_budget
-            if lp.shape[ax] != 1 and orig_dim not in dims:
+            lp = _site_log_prob(site)
+        if jnp.ndim(lp) > R:
+            raise NotImplementedError(
+                f"markov: the factor of site '{site['name']}' depends on "
+                f"enumeration dim {-jnp.ndim(lp)} outside the chain; markov "
+                "transitions may only depend on the previous state")
+        lp = jnp.reshape(lp, (1,) * (R - jnp.ndim(lp)) + jnp.shape(lp))
+        summed = tuple(a for a in inner if lp.shape[a] != 1)
+        if summed:
+            lp = jnp.sum(lp, axis=summed, keepdims=True)
+        for ax in range(R):
+            if lp.shape[ax] != 1 and ax not in enum_axes \
+                    and ax not in row_axes:
                 raise NotImplementedError(
                     f"markov: the factor of site '{site['name']}' depends on "
-                    f"enumeration dim {orig_dim} outside the chain; markov "
+                    f"enumeration dim {ax - R} outside the chain; markov "
                     "transitions may only depend on the previous state")
-        acc = acc + lp
-    shape = tuple(acc.shape[nd + d + plate_budget] for d in dims)
-    return acc.reshape(shape)
+        if len(dims) == 2 and lp.shape[enum_axes[0]] != 1:
+            pair = pair + lp
+        else:
+            unary = unary + lp
+    sizes = [n for _, n in rows]
+    unary = on_rows(unary, row_axes + enum_axes[-1:], sizes + [K])
+    unary = unary.reshape(B, K)
+    keep = (jnp.ones((B,), bool) if keep is None
+            else on_rows(keep, row_axes, sizes).reshape(B))
+    if len(dims) == 1:
+        return unary, None, keep
+    if any(pair.shape[a] != 1 for a in row_axes):
+        pair = on_rows(pair, row_axes + enum_axes, sizes + [K, K])
+        pair = pair.reshape(B, K, K)
+    else:
+        pair = on_rows(pair, enum_axes, [K, K])
+    return unary, pair, keep
 
 
 def markov(fn, init, xs, *, name: str = "markov"):
@@ -495,23 +577,37 @@ def markov(fn, init, xs, *, name: str = "markov"):
     new carry); every other site inside must be observed.  ``xs`` is a pytree
     of arrays with a leading time axis of length T.
 
+    Plates open around the call batch the chain: each of their elements (a
+    row, e.g. one sequence of a corpus) has a state of its own, and all
+    rows are eliminated together.  Plates opened inside ``fn`` may hold
+    observed sites only (they are summed given the state); a state inside
+    one raises ``RPL014``.  Where the state site's
+    mask is False (e.g. ``t >= length`` of a padded sequence), that row
+    leaves the step out: its state holds and its forward message passes
+    unchanged, so a padded row scores exactly its unpadded length.
+
     Semantics depend on context:
 
     - plain simulation (``seed``/``trace``, no enumeration active): runs the
       transition T times under per-step :class:`~repro.core.handlers.scope`
       prefixes (``{name}/{t}/...``) and returns the stacked carries ``(T,
       ...)``;
-    - enum-aware ``log_density``: computes per-step factors ``log p(z_t |
-      z_{t-1}) + log p(obs_t | z_t)`` for all steps at once (one ``vmap``
-      over time), eliminates the state along the time axis with a
-      ``lax.scan`` over :func:`repro.kernels.ops.enum_contract` — O(T·K²),
-      fully jit-compiled — and contributes the chain's marginal likelihood as
-      a single ``{name}_marginal`` factor site (outer ``scale``/``mask``
-      handlers apply to it).  Returns ``None``: the carry must not be
-      consumed downstream under marginalization;
-    - :func:`infer_discrete`: forward-filters, backward-samples the state
-      path, records it as a ``deterministic`` site named ``{name}``, and
-      returns the sampled ``(T,)`` states (so downstream code runs on
+    - enum-aware ``log_density``: computes per-step factors for all steps
+      and rows at once (one ``vmap`` over time), split into those that read
+      the previous state (``log p(z_t | z_{t-1})``, one (K, K) matrix for
+      all rows where no row varies it) and those that do not (``log
+      p(obs_t | z_t)``, (B, K)), eliminates the state along the time axis
+      with :func:`repro.kernels.ops.enum_contract_chain` — the forward
+      algorithm of every row in one call, O(T·B·K²), T-independent
+      program, under the ``repro.markov`` scope — and contributes each
+      row's marginal likelihood through one ``{name}_marginal`` factor
+      site shaped like the enclosing plates (their ``scale``/``mask`` and
+      outer handlers apply to it).  Returns ``None``: the carry must not be consumed
+      downstream under marginalization;
+    - :func:`infer_discrete`: forward-filters, backward-samples every row's
+      state path, records it as a ``deterministic`` site named ``{name}``,
+      and returns the sampled states, ``(T,)`` or ``(*plate sizes, T)``
+      with masked steps marked ``-1`` (so downstream code runs on
       concrete draws).
     """
     leaves = jax.tree_util.tree_leaves(xs)
@@ -534,8 +630,13 @@ def markov(fn, init, xs, *, name: str = "markov"):
         return jax.tree_util.tree_map(lambda *v: jnp.stack(v), *carries)
 
     if getattr(handler, "_markov_local", False):
-        raise NotImplementedError("nested markov is not supported")
-    _assert_no_active_plates("markov")
+        raise ReproNotImplementedError(
+            f"markov '{name}' is nested in another markov transition; "
+            "nested chains are not supported (eliminate one chain whose "
+            "state is the product of both)", code="RPL014", site=name)
+    plates = _enclosing_plates()
+    rows = tuple(sorted((p._frame.dim, p._frame.size) for p in plates))
+    row_dims = [d for d, _ in rows]
     x0 = jax.tree_util.tree_map(lambda a: a[0], xs)
 
     if isinstance(handler, _EnumProbe):
@@ -543,7 +644,7 @@ def markov(fn, init, xs, *, name: str = "markov"):
         # site are counted, then hand back a carry of the right structure
         handler.found = True
         with scope(prefix=f"{name}/probe"), config_enumerate(), \
-                _RequireEnumerable():
+                _RequireEnumerable(), _StateOnRows(row_dims, name):
             carry = fn(init, x0)
         return jax.tree_util.tree_map(
             lambda v: jnp.broadcast_to(jnp.asarray(v),
@@ -551,82 +652,112 @@ def markov(fn, init, xs, *, name: str = "markov"):
 
     plate_budget = -handler.first_available_dim - 1
 
-    # --- step 0: discover the state site and its support ------------------
-    e0 = enum(first_available_dim=handler._next, strict=True)
-    e0._markov_local = True
-    with block(), trace() as tr0, e0, config_enumerate():
-        fn(init, x0)
-    if len(e0._alloc) != 1:
-        raise ValueError(
-            f"markov '{name}': the transition must contain exactly one "
-            f"enumerable latent state site, found {list(e0._alloc) or 'none'}")
-    state_name, (d0, K) = next(iter(e0._alloc.items()))
-    d_cur = handler.allocate(K, f"_markov/{name}/cur")
-    assert d_cur == d0
-    d_prev = handler.allocate(K, f"_markov/{name}/prev")
-    support = tr0[state_name]["fn"].enumerate_support(expand=False)
-    support_flat = support.reshape(-1)
-    alpha0 = _step_factor(tr0, plate_budget, (d_cur,))          # (K,)
+    with jax.named_scope(SCOPES.markov):
+        # --- step 0: discover the state site and its support --------------
+        e0 = enum(first_available_dim=handler._next, strict=True)
+        e0._markov_local = True
+        with block(), trace() as tr0, e0, config_enumerate(), \
+                _StateOnRows(row_dims, name):
+            fn(init, x0)
+        if len(e0._alloc) != 1:
+            raise ValueError(
+                f"markov '{name}': the transition must contain exactly one "
+                "enumerable latent state site, found "
+                f"{list(e0._alloc) or 'none'}")
+        state_name, (d0, K) = next(iter(e0._alloc.items()))
+        d_cur = handler.allocate(K, f"_markov/{name}/cur")
+        assert d_cur == d0
+        d_prev = handler.allocate(K, f"_markov/{name}/prev")
+        support = tr0[state_name]["fn"].enumerate_support(expand=False)
+        support_flat = support.reshape(-1)
+        alpha0, _, keep0 = _step_factors(tr0, plate_budget, (d_cur,), rows,
+                                         K, state_name)          # (B, K)
 
-    # --- steps 1..T-1: transition factors, vectorized over time -----------
-    if T > 1:
+        # --- steps 1..T-1: factors, vectorized over time ------------------
         prev_value = support_flat.reshape((K,) + (1,) * (-d_prev - 1))
         e1 = enum(first_available_dim=d_cur, strict=True,
                   extra_dims={d_prev: K})
         e1._markov_local = True
 
-        def step_factor(x_t):
-            with block(), trace() as tr, e1, config_enumerate():
+        def step_factors(x_t):
+            with block(), trace() as tr, e1, config_enumerate(), \
+                    _StateOnRows(row_dims, name):
                 fn(prev_value, x_t)
             (nm, (d, k)), = e1._alloc.items()
             if (d, k) != (d_cur, K) or nm != state_name:
                 raise ValueError(
-                    f"markov '{name}': transition structure changed between "
-                    f"steps (state site '{state_name}' with {K} states "
-                    f"became '{nm}' with {k})")
-            return _step_factor(tr, plate_budget, (d_prev, d_cur))
+                    f"markov '{name}': transition structure changed "
+                    f"between steps (state site '{state_name}' with {K} "
+                    f"states became '{nm}' with {k})")
+            return _step_factors(tr, plate_budget, (d_prev, d_cur), rows,
+                                 K, state_name)
 
-        xs_rest = jax.tree_util.tree_map(lambda a: a[1:], xs)
-        mats = jax.vmap(step_factor)(xs_rest)                   # (T-1, K, K)
-    else:
-        mats = jnp.zeros((0, K, K), alpha0.dtype)
+        # (T-1, B, K), (T-1, K, K) or (T-1, B, K, K), (T-1, B)
+        steps = jax.vmap(step_factors)(
+            jax.tree_util.tree_map(lambda a: a[1:], xs))
 
-    from repro.kernels import ops
+        from repro.kernels import ops
 
+        if handler.mode == "marginal":
+            B, pair = alpha0.shape[0], steps[1]
+            if not ops.pallas_enabled("enum_contract_chain"):
+                path = "reference"
+            else:
+                from repro.kernels.enum_contract import chain_route
+                path = chain_route(T - 1, K, pair.ndim == 4)
+            route = {"route": "plate_batched", "path": path, "rows": B,
+                     "steps": T - 1, "states": K}
+
+            # the forward algorithm of every row: alpha_t = logsumexp_i(
+            # alpha_{t-1}[i] + pair_t[i, :]) + unary_t, a masked row holding
+            per_row = ops.enum_contract_chain(alpha0, *steps)   # (B,)
+        else:
+            # --- mode == "sample": forward filter, backward sample --------
+            def fwd(alpha, step):
+                unary, pair, keep = step
+                new = ops.enum_contract(alpha, pair) + unary
+                new = jnp.where(keep[:, None], new, alpha)
+                return new, new
+
+            _, tail = lax.scan(fwd, alpha0, steps)
+            alphas = jnp.concatenate([alpha0[None], tail], axis=0)  # (T,B,K)
+            key_last, key_rest = random.split(handler.fresh_key())
+            z_last = random.categorical(key_last, alphas[-1])       # (B,)
+            _, pairs, keeps = steps
+
+            def back(z_next, inp):
+                # p(z_t | z_{t+1}) ~ alpha_t(z_t) pair_{t+1}(z_t, z_{t+1});
+                # a held step (masked t+1) keeps the state
+                alpha_t, pair_next, keep_next, k = inp
+                col = (pair_next[:, z_next].T if pair_next.ndim == 2 else
+                       jnp.take_along_axis(pair_next, z_next[:, None, None],
+                                           axis=-1)[..., 0])         # (B, K)
+                z = random.categorical(k, alpha_t + col)
+                z = jnp.where(keep_next, z, z_next)
+                return z, z
+
+            _, zs = lax.scan(back, z_last,
+                             (alphas[:-1], pairs, keeps,
+                              random.split(key_rest, T - 1)), reverse=True)
+            idx = jnp.concatenate([zs, z_last[None]], axis=0)       # (T, B)
+            kept = jnp.concatenate([keep0[None], keeps], axis=0)
+            states = jnp.where(kept, support_flat[idx],
+                               jnp.asarray(-1, support_flat.dtype)).T
+
+    row_sizes = tuple(n for _, n in rows)
     if handler.mode == "marginal":
-        def fwd(alpha, mat):
-            return ops.enum_contract(alpha, mat), None
-
-        alpha_T, _ = lax.scan(fwd, alpha0, mats)
-        total = jax.nn.logsumexp(alpha_T, axis=-1)
+        # one factor per row, at the enclosing plates' dims
+        at_plates = [1] * (-row_dims[0] if rows else 0)
+        for d, n in rows:
+            at_plates[d] = n
+        # the site carries the route the elimination took, fixed at trace
+        # time (rows per chain); the executor reports it
         _sample(f"{name}_marginal",
-                _dist.Delta(jnp.zeros(()), log_density=total),
-                obs=jnp.zeros(()))
+                _dist.Delta(jnp.zeros(at_plates),
+                            log_density=per_row.reshape(at_plates)),
+                obs=jnp.zeros(at_plates), infer={"markov_route": route})
         return None
-
-    # --- mode == "sample": forward filter, backward sample -----------------
-    def fwd(alpha, mat):
-        new = ops.enum_contract(alpha, mat)
-        return new, new
-
-    _, tail = lax.scan(fwd, alpha0, mats)
-    alphas = jnp.concatenate([alpha0[None], tail], axis=0)      # (T, K)
-    key_last, key_rest = random.split(handler.fresh_key())
-    z_last = random.categorical(key_last, alphas[-1])
-    if T > 1:
-        keys = random.split(key_rest, T - 1)
-
-        def back(z_next, inp):
-            alpha_t, mat_next, k = inp
-            z = random.categorical(k, alpha_t + mat_next[:, z_next])
-            return z, z
-
-        _, zs = lax.scan(back, z_last, (alphas[:-1], mats, keys),
-                         reverse=True)
-        idx = jnp.concatenate([zs, z_last[None]], axis=0)
-    else:
-        idx = z_last[None]
-    states = support_flat[idx]
+    states = states.reshape(row_sizes + (T,))
     _deterministic(name, states)
     handler.samples[name] = states
     return states

@@ -576,8 +576,8 @@ class MCMC:
                 if count_div and out is not None and "diverging" in out:
                     if forens is not None:
                         # the mask fetch is the same chunk-boundary sync
-                        # the plain counter pays; full positions are
-                        # gathered only for divergent draws (see
+                        # the plain counter pays; positions are fetched
+                        # only for chunks with a divergent draw (see
                         # obs/divergences.py)
                         mask = jax.device_get(out["diverging"])
                         delta_div = int(np.sum(mask))
@@ -806,11 +806,18 @@ class MCMC:
                 final["convergence"] = self.monitor.decision
             if tele.metrics and setup.metrics_fn is not None:
                 final["metrics"] = tele.buffer.summary("sample")
+            # the fused GLM gradient's route (glm._slab_value_and_grad) and
+            # a markov elimination's (enum.markov), fixed when the chunk
+            # programs were traced
             route = getattr(setup.potential_fn, "glm_route", None)
             if route:
-                # the fused GLM gradient's route, fixed when the chunk
-                # programs were traced (glm._slab_value_and_grad)
                 final["glm_route"] = dict(route)
+            route = getattr(setup.potential_fn, "markov_route", None)
+            if route:
+                # vectorized chains put all their rows through each call
+                final["markov_route"] = dict(route, rows=route["rows"] * (
+                    self.num_chains if self.chain_method == "vectorized"
+                    else 1))
             tele.finish_run(final)
         return self
 

@@ -100,6 +100,12 @@ def constrain_fn(model, model_args, model_kwargs, transforms, params_uncon):
 
 def potential_energy(model, model_args, model_kwargs, transforms, params_uncon):
     """-log p(constrained(z)) - log|det J(z)| on unconstrained space."""
+    return _potential_and_trace(model, model_args, model_kwargs, transforms,
+                                params_uncon)[0]
+
+
+def _potential_and_trace(model, model_args, model_kwargs, transforms,
+                         params_uncon):
     params_con = {}
     log_det = jnp.zeros(())
     for name, t in transforms.items():
@@ -108,8 +114,8 @@ def potential_energy(model, model_args, model_kwargs, transforms, params_uncon):
         params_con[name] = x
         ladj = t.log_abs_det_jacobian(u, x)
         log_det = log_det + jnp.sum(ladj)
-    log_joint, _ = log_density(model, model_args, model_kwargs, params_con)
-    return -(log_joint + log_det)
+    log_joint, tr = log_density(model, model_args, model_kwargs, params_con)
+    return -(log_joint + log_det), tr
 
 
 def initialize_model_structure(rng_key, model, model_args=(),
@@ -145,9 +151,20 @@ def initialize_model_structure(rng_key, model, model_args=(),
         proto[name] = t.inv(value)
     flat_proto, unravel_fn = ravel_pytree(proto)
 
+    markov_route = {}
+
     def potential_flat(zflat):
-        return potential_energy(model, model_args, model_kwargs, transforms,
-                                unravel_fn(zflat))
+        u, tr = _potential_and_trace(model, model_args, model_kwargs,
+                                     transforms, unravel_fn(zflat))
+        for site in tr.values():
+            markov_route.update((site.get("infer") or {})
+                                .get("markov_route", {}))
+        return u
+
+    # the route a markov elimination took, which its marginal site carries
+    # (enum.markov), read while the programs are traced; the executor
+    # reports it
+    potential_flat.markov_route = markov_route
 
     def constrain(zflat):
         return transform_fn(transforms, unravel_fn(zflat))

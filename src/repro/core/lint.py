@@ -403,7 +403,10 @@ def check_time_independence(make_fn: Callable, sizes: Tuple[int, ...]
     ``make_fn(T) -> (fn, args)`` builds the program at one time-axis length.
     ``markov`` elimination runs inside ``lax.scan``, so the traced program
     must have the *same* equation count at every T — growth means the chain
-    got unrolled (O(T) code size, O(T) compile time).
+    got unrolled (O(T) code size, O(T) compile time).  A chain batched by
+    plates around ``markov`` (RPL014 refuses a state inside a plate opened
+    within the transition) still runs one contraction per step over all
+    its rows.
     """
     counts = {}
     for t in sizes:

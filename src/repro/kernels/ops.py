@@ -81,6 +81,9 @@ OP_TABLE = (
            ("repro.kernels.ref", "mala_step"), False, 1e-6),
     OpSpec("enum_contract", ("repro.kernels.enum_contract", "enum_contract"),
            ("repro.kernels.ref", "enum_contract"), True, 0.0),
+    OpSpec("enum_contract_chain", ("repro.kernels.enum_contract",
+                                   "enum_contract_chain"),
+           ("repro.kernels.ref", "enum_contract_chain"), True, 0.0),
     OpSpec("rmsnorm", ("repro.kernels.rmsnorm", "rmsnorm"),
            ("repro.kernels.ref", "rmsnorm"), False, 2e-5),
     OpSpec("softmax_xent", ("repro.kernels.softmax_xent", "softmax_xent"),
@@ -225,6 +228,17 @@ def enum_contract(log_alpha, log_mat):
         from .enum_contract import enum_contract as _k
         return _k(log_alpha, log_mat, interpret=_STATE["interpret"])
     return ref.enum_contract(log_alpha, log_mat)
+
+
+def enum_contract_chain(alpha0, unary, pair, keep):
+    """Log partition of every row of a batched chain (the forward
+    algorithm of a plate-batched ``markov``): the whole chain in one
+    kernel call and its gradient in one more under Pallas; one
+    ``enum_contract`` per step otherwise."""
+    if pallas_enabled("enum_contract_chain"):
+        from .enum_contract import enum_contract_chain as _k
+        return _k(alpha0, unary, pair, keep, interpret=_STATE["interpret"])
+    return ref.enum_contract_chain(alpha0, unary, pair, keep)
 
 
 def rmsnorm(x, weight, eps=1e-6):

@@ -363,3 +363,51 @@ def enum_contract(log_alpha, log_mat):
     for i in range(1, e.shape[-2]):
         s = s + e[..., i, :]
     return jnp.where(jnp.isfinite(m), jnp.log(s) + m_safe, -jnp.inf)
+
+
+def enum_contract_chain(alpha0, unary, pair, keep):
+    """Log partition of every row of a batched chain: the forward algorithm
+    over T - 1 steps, one :func:`enum_contract` per step for all rows.
+
+    ``alpha0`` (B, K) is each row's first message, ``unary`` (T-1, B, K)
+    the factors of each step that do not read the previous state,
+    ``pair`` (T-1, K, K) those that do (one matrix for every row, or
+    (T-1, B, K, K)), ``keep`` (T-1, B) whether the row takes the step: a
+    row that does not holds its message.  Returns ``log Z`` (B,).
+    """
+    return chain_scan(enum_contract, alpha0, unary, pair, keep)
+
+
+def logsumexp_states(x):
+    """``logsumexp`` over the last axis in the order :func:`enum_contract`
+    pins (max, exp-sum left to right, log; an axis that is -inf
+    throughout gives -inf)."""
+    m = jnp.max(x, axis=-1)
+    m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
+    s = jnp.exp(x[..., 0] - m_safe)
+    for k in range(1, x.shape[-1]):
+        s = s + jnp.exp(x[..., k] - m_safe)
+    return jnp.where(jnp.isfinite(m), jnp.log(s) + m_safe, -jnp.inf)
+
+
+def chain_scan(contract, alpha0, unary, pair, keep):
+    """:func:`enum_contract_chain` with ``contract`` as each step's
+    contraction.  Each message is shifted so that its largest state is 0,
+    and the shifts are summed into ``log Z`` one step at a time: a message
+    of a long chain then stays within a few emissions of 0 instead of
+    growing to ``log Z`` itself, where f32 has too few digits for the
+    posterior that the gradient is made of."""
+    def shifted(x):
+        m = jnp.max(x, axis=-1)
+        return x - jnp.where(jnp.isfinite(m), m, 0.0)[..., None], m
+
+    def step(carry, inp):
+        alpha, log_z = carry
+        u, p, k = inp
+        new, m = shifted(contract(alpha, p) + u)
+        return (jnp.where(k[:, None], new, alpha),
+                jnp.where(k, log_z + m, log_z)), None
+
+    (alpha, log_z), _ = jax.lax.scan(step, shifted(alpha0),
+                                     (unary, pair, keep))
+    return log_z + logsumexp_states(alpha)

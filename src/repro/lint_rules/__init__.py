@@ -64,8 +64,9 @@ RULES = {r.code: r for r in [
          "(deterministic arange fallback)", WARN, "warning"),
     Rule("RPL013", "enumerate mark on a non-enumerable (continuous) site",
          ERROR, "error"),
-    Rule("RPL014", "markov combinator inside an active plate", ERROR,
-         "error"),
+    Rule("RPL014", "markov state site inside a plate opened within the "
+         "transition (open the plate around markov), or nested markov",
+         ERROR, "error"),
     Rule("RPL015", "handler state baked into the model callable "
          "(seed key captured at trace time)", WARN, None,
          "a seed handler in the model chain re-splits its captured key per "

@@ -98,6 +98,13 @@ def _sample_inputs(name, key):
     if name == "enum_contract":
         return (random.normal(ks[0], (7,)),
                 random.normal(ks[1], (7, 5))), {}
+    if name == "enum_contract_chain":
+        steps, b, k = 6, 11, 5
+        keep = random.uniform(ks[3], (steps, b)) < 0.8
+        return (random.normal(ks[0], (b, k)),
+                random.normal(ks[1], (steps, b, k)),
+                jax.nn.log_softmax(random.normal(ks[2], (steps, k, k))),
+                keep), {}
     if name == "rmsnorm":
         x = random.normal(ks[0], (4, 64, 128))
         w = random.normal(ks[1], (128,)) * 0.1 + 1.0

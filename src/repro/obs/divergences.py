@@ -5,10 +5,11 @@ A divergence count tells you a run has a problem; it does not tell you
 unconstrained position, energy, step size, iteration — captured at the
 executor's chunk drain from the collect outputs the chunk program already
 produced.  Cost discipline: the ``diverging`` mask comes off-device at the
-boundary the executor already pays for the divergence counter; full
-positions are fetched *only for divergent draws* (a gather on device, then
-one small transfer), so a clean run adds zero transfers and a dirty one
-pays proportional to its divergences, capped by the ring.
+boundary the executor already pays for the divergence counter; positions
+are fetched *only for chunks with a divergent draw* (the chunk's
+collected arrays, indexed on the host: a gather on the device would
+compile anew for each count of divergent draws), so a clean run adds zero
+transfers and a dirty one pays one transfer per divergent chunk.
 
 At the end of a run the executor attaches a per-dimension baseline
 (mean/std over all collected draws) and the telemetry layer writes
@@ -53,11 +54,11 @@ class DivergenceRing:
         if idx.size == 0:
             return 0
         cs, ts = idx[:, 0], idx[:, 1]
-        # gather on device, transfer only the divergent rows
-        z_rows = np.asarray(out["z"][cs, ts], np.float64)
+        # fetched whole and indexed on the host: no program is compiled
+        z_rows = np.asarray(out["z"], np.float64)[cs, ts]
         energy_key = "energy" if "energy" in out else "potential_energy"
-        energies = np.asarray(out[energy_key][cs, ts], np.float64)
-        steps = (np.asarray(out["step_size"][cs, ts], np.float64)
+        energies = np.asarray(out[energy_key], np.float64)[cs, ts]
+        steps = (np.asarray(out["step_size"], np.float64)[cs, ts]
                  if "step_size" in out else np.full(len(cs), np.nan))
         for j in range(len(cs)):
             self.records.append({

@@ -24,6 +24,7 @@ class Scopes(NamedTuple):
     integrator: str = "repro.integrator"  # leapfrog kicks and drift
     tree: str = "repro.tree"              # NUTS trajectory building
     adapt: str = "repro.adapt"            # warmup step-size / mass adaptation
+    markov: str = "repro.markov"          # discrete chain elimination
 
 
 SCOPES = Scopes()

@@ -277,8 +277,11 @@ def test_markov_guards():
         return z
 
     def in_plate(w):
-        with pc.plate("batch", 2):
-            markov(step, 0, w)
+        # the state inside a plate opened within the transition: RPL014
+        def plated_step(z_prev, w_t):
+            with pc.plate("batch", 2):
+                return step(z_prev, w_t)
+        markov(plated_step, 0, w)
 
     with pytest.raises(NotImplementedError, match="plate"):
         log_density(config_enumerate(in_plate), (W,), {}, {})

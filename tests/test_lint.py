@@ -141,16 +141,19 @@ def _enumerate_continuous_site():
     return model, (), {}, {}
 
 
-def _markov_inside_plate():
+def _markov_state_in_step_plate():
+    # the plate opened inside the transition, around the state: every
+    # sequence would share one state (the plate belongs around markov)
     def step(carry, x_t):
-        z = pc.sample("z", dist.Categorical(probs=jnp.ones(2) / 2),
-                      infer={"enumerate": "parallel"})
-        pc.sample("obs", dist.Normal(z.astype(jnp.float32), 1.0), obs=x_t)
+        with pc.plate("sequences", 4):
+            z = pc.sample("z", dist.Categorical(probs=jnp.ones(2) / 2),
+                          infer={"enumerate": "parallel"})
+            pc.sample("obs", dist.Normal(z.astype(jnp.float32), 1.0),
+                      obs=x_t)
         return z
 
     def model(x):
-        with pc.plate("outer", 4):
-            markov(step, 0, x)
+        markov(step, 0, x)
     return model, (jnp.zeros(3),), {}, {}
 
 
@@ -175,7 +178,7 @@ DEFECTS = [
     Defect("RPL011", "x", "'x'", _replay_observed_latent_mismatch),
     Defect("RPL012", None, "subsample", _unseeded_subsample),
     Defect("RPL013", "x", "'x'", _enumerate_continuous_site),
-    Defect("RPL014", "outer", "'outer'", _markov_inside_plate),
+    Defect("RPL014", "sequences", "'sequences'", _markov_state_in_step_plate),
     Defect("RPL015", None, "seed", _seed_baked_into_model),
 ]
 

@@ -18,7 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.infer.glm import _make_slab_nll
 from repro.kernels import ops
-from repro.kernels.enum_contract import enum_contract
+from repro.kernels.enum_contract import enum_contract, enum_contract_chain
 from repro.kernels.glm_potential import (
     glm_potential_grad,
     glm_potential_grad_slab,
@@ -141,3 +141,47 @@ def test_enum_contract_compiles(one_chip):
     K, T = 8, 120
     _assert_kernel(enum_contract, _spec(one_chip, T, K),
                    _spec(one_chip, T, K, K), name="enum_contract")
+
+
+def test_enum_contract_plate_batched_compiles(one_chip):
+    """One step of the plate-batched markov elimination of the JSB HMM:
+    4 chains x 229 sequences = 916 rows of 16 states, under the chain
+    vmap and differentiated through the kernel's custom_vjp."""
+    chains, sequences, K = 4, 229, 16
+
+    def value_and_grads(alpha, mat):
+        def one_chain(a, m):
+            return jnp.sum(enum_contract(a, m))
+        return jax.vmap(jax.value_and_grad(one_chain, argnums=(0, 1)))(
+            alpha, mat)
+
+    _assert_kernel(value_and_grads, _spec(one_chip, chains, sequences, K),
+                   _spec(one_chip, chains, sequences, K, K),
+                   name="enum_contract")
+
+
+@pytest.mark.parametrize("sequences", [229, 5000])
+def test_enum_contract_chain_compiles(one_chip, sequences):
+    """The JSB HMM's whole chain, value and gradient: 4 chains, each 229
+    sequences over 128 steps of 16 states, under the chain vmap; the
+    forward and backward kernels each hold a block of at most 256 rows'
+    steps in VMEM, so a corpus of 5,000 sequences compiles too."""
+    chains, steps, K = 4, 128, 16
+
+    def value_and_grads(alpha0, unary, pair, keep):
+        def one_chain(a, u, p):
+            return jnp.sum(enum_contract_chain(a, u, p, keep))
+        return jax.vmap(jax.value_and_grad(one_chain, argnums=(0, 1, 2)))(
+            alpha0, unary, pair)
+
+    keep = jax.ShapeDtypeStruct((steps, sequences), jnp.bool_,
+                                sharding=one_chip)
+    text = jax.jit(value_and_grads).lower(
+        _spec(one_chip, chains, sequences, K),
+        _spec(one_chip, chains, steps, sequences, K),
+        _spec(one_chip, chains, steps, K, K), keep).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any("enum_contract_chain_vjp" in c for c in calls), calls
+    assert any("enum_contract_chain" in c and "vjp" not in c
+               for c in calls), calls
